@@ -1,17 +1,17 @@
 """Shared profile-data collection with an on-disk cache.
 
 The paper collects 17,300 + 5,500 data points across days of GPU time; on
-this 1-core container the benchmark suite collects a scaled-down set (a
-few hundred points; BENCH_FULL=1 widens the grid) once, cached in
-``artifacts/profiles.jsonl`` keyed by configuration, so every MRE
-benchmark reads the same corpus.
+one host the benchmark suite collects a scaled-down set (a few hundred
+points; BENCH_FULL=1 widens the grid) once, cached in
+``artifacts/profiles.jsonl`` keyed by configuration and by the device it
+was measured on, so every MRE benchmark reads the same corpus and never
+another device's labels. A profile that fails stops the collection.
 """
 
 from __future__ import annotations
 
 import json
 import os
-import sys
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -91,8 +91,15 @@ def lm_grid() -> List[Dict]:
     return out
 
 
+def _device() -> Dict[str, str]:
+    """The device this process measures on, as JAX reports it."""
+    import jax
+    d = jax.devices()[0]
+    return {"platform": d.platform, "device_kind": d.device_kind}
+
+
 def _key(combo: Dict) -> str:
-    return json.dumps(combo, sort_keys=True)
+    return json.dumps({**combo, **_device()}, sort_keys=True)
 
 
 def _load_cache() -> Dict[str, Dict]:
@@ -164,10 +171,9 @@ def collect(combos: List[Dict], verbose: bool = True) -> List[ProfileRecord]:
         t0 = time.time()
         try:
             rec = _profile(combo)
-        except Exception as e:  # pragma: no cover - robustness on odd combos
-            print(f"[collect] FAIL {key}: {type(e).__name__}: {e}",
-                  file=sys.stderr)
-            continue
+        except Exception as e:
+            e.add_note(f"[collect] while profiling {key}")
+            raise
         with open(CACHE, "a") as f:
             f.write(json.dumps({"key": key,
                                 "record": record_to_json(rec)}) + "\n")
@@ -186,6 +192,9 @@ def corpus() -> Tuple[List[ProfileRecord], List[ProfileRecord], List[ProfileReco
 
 
 def all_cached() -> List[ProfileRecord]:
-    """Every record ever profiled (incl. batch sweeps from other benches) —
-    the densest corpus available, closest to the paper's 17k-point grid."""
-    return [record_from_json(d) for d in _load_cache().values()]
+    """Every record ever profiled on this device (incl. batch sweeps from
+    other benches) — the densest corpus available, closest to the paper's
+    17k-point grid."""
+    dev = _device()
+    return [record_from_json(d) for k, d in _load_cache().items()
+            if {f: json.loads(k).get(f) for f in dev} == dev]

@@ -21,7 +21,7 @@ from repro.core.automl.search import FittedEnsemble, fit_automl
 from repro.core.features import ProfileRecord, design_matrix, mre, targets
 from repro.core.graphfeat import WLGraphEmbedder
 
-HBM_PER_DEVICE = 16 * 2**30  # v5e target; host budget used on CPU
+HBM_PER_DEVICE = 16 * 2**30  # nominal v5e HBM; the launcher reads the real limit
 
 
 class DNNAbacus:

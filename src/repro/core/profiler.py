@@ -4,7 +4,7 @@ For every configuration we build a *real* jitted training step (model
 forward + CE loss + backward + optimizer update), then record:
 
   time_s     — median wall-clock of ``steps`` executed steps (compile
-               excluded), on the host backend;
+               excluded), on JAX's default device;
   mem_bytes  — XLA peak bytes from ``compiled.memory_analysis()`` (the
                AOT analogue of the paper's pynvml polling — see DESIGN.md);
   flops      — loop-aware HLO FLOPs (repro.analysis.hlo), which doubles
@@ -95,21 +95,23 @@ def profile_step(step_fn, args, steps: int = 3,
            + getattr(ma, "output_size_in_bytes", 0)
            + getattr(ma, "temp_size_in_bytes", 0)
            - getattr(ma, "alias_size_in_bytes", 0))
-    # run
-    concrete = jax.tree.map(
-        lambda s: jnp.zeros(s.shape, s.dtype) if hasattr(s, "shape") else s,
-        args)
-    out = compiled(*concrete)
-    jax.block_until_ready(out)
+    # run on fresh zero arguments, made ready before the clock starts; the
+    # previous call's arrays are dropped first, so a full-width step holds
+    # one set of arguments and outputs at a time
+    def zeros():
+        return jax.block_until_ready(jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype) if hasattr(s, "shape")
+            else s, args))
+
+    jax.block_until_ready(compiled(*zeros()))
     times = []
     for _ in range(steps):
-        concrete2 = jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype) if hasattr(s, "shape") else s,
-            args)
+        concrete = zeros()
         t0 = time.perf_counter()
-        out = compiled(*concrete2)
+        out = compiled(*concrete)
         jax.block_until_ready(out)
         times.append(time.perf_counter() - t0)
+        del out, concrete
     return {"time_s": float(np.median(times)), "mem_bytes": float(mem),
             "flops": cost.flops, "nsm_edges": edges}
 

@@ -23,11 +23,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map
-
 Pytree = Any
 
 

@@ -54,32 +54,56 @@ class StepRunner:
         return None
 
     def __call__(self, *args):
-        last_err = None
-        for attempt in range(self.cfg.max_retries + 1):
+        first_err = None
+        attempts = self.cfg.max_retries + 1
+        for attempt in range(attempts):
             t0 = time.perf_counter()
             try:
-                out = self.step_fn(*args)
-                out = jax_block(out)
-                dt = time.perf_counter() - t0
-                ref = self._reference_time()
-                if (ref is not None and dt > self.cfg.straggler_factor * ref):
-                    self.stragglers += 1
-                    if self.on_straggler:
-                        self.on_straggler(len(self.history), dt)
-                if (self.cfg.watchdog_timeout_s
-                        and dt > self.cfg.watchdog_timeout_s):
-                    raise StepFailure(
-                        f"watchdog: step took {dt:.1f}s "
-                        f"> {self.cfg.watchdog_timeout_s}s")
-                self.history.append(dt)
-                return out
+                out = jax_block(self.step_fn(*args))
             except StepFailure:
                 raise
-            except Exception as e:  # transient device/runtime errors
-                last_err = e
-                self.retries += 1
+            except Exception as e:
+                if first_err is None:
+                    first_err = e
+                if _hopeless(e, args):
+                    raise StepFailure(
+                        f"step failed on attempt {attempt + 1} and cannot "
+                        f"be retried: {type(e).__name__}: {e}") from first_err
+                if attempt + 1 < attempts:
+                    self.retries += 1
+                continue
+            dt = time.perf_counter() - t0
+            ref = self._reference_time()
+            if ref is not None and dt > self.cfg.straggler_factor * ref:
+                self.stragglers += 1
+                if self.on_straggler:
+                    self.on_straggler(len(self.history), dt)
+            if (self.cfg.watchdog_timeout_s
+                    and dt > self.cfg.watchdog_timeout_s):
+                raise StepFailure(
+                    f"watchdog: step took {dt:.1f}s "
+                    f"> {self.cfg.watchdog_timeout_s}s")
+            self.history.append(dt)
+            return out
         raise StepFailure(
-            f"step failed after {self.cfg.max_retries + 1} attempts") from last_err
+            f"step failed after {attempts} attempts") from first_err
+
+
+def _hopeless(err: Exception, args) -> bool:
+    """True for a failure that every retry would repeat.
+
+    Argument errors: ``TypeError``/``ValueError`` (jit's sharding
+    mismatches among them) and runtime errors with XLA's
+    ``INVALID_ARGUMENT`` status. And any failure after the call consumed
+    its donated buffers, which a retry would need again.
+    """
+    import jax
+    if isinstance(err, (TypeError, ValueError)):
+        return True
+    if str(err).startswith("INVALID_ARGUMENT"):
+        return True
+    return any(getattr(x, "is_deleted", lambda: False)()
+               for x in jax.tree.leaves(args))
 
 
 def jax_block(out):

@@ -1,8 +1,9 @@
 """Jitted wrappers: model-facing entry points for the Pallas kernels.
 
-These adapt model-layout tensors to kernel layouts, pick block sizes, and
-fall back to the reference for shapes the kernels do not tile (tiny or
-ragged extents during smoke tests).
+These adapt model-layout tensors to kernel layouts and pick block sizes
+on the TPU's tiling. A shape they cannot tile raises ``ValueError``;
+nothing falls back, so the kernel that runs is the kernel that was asked
+for.
 """
 
 from __future__ import annotations
@@ -18,11 +19,22 @@ from repro.kernels import rmsnorm as rn
 from repro.kernels import ssd_scan as ssd
 
 
+# rows of a block must be a multiple of this, unless the block spans the
+# whole extent (the (8, 128) tiling's sublane side)
+SUBLANES = 8
+
+
 def _pick_block(s: int, target: int) -> int:
-    b = min(target, s)
-    while s % b:
+    """The largest block of at most ``target`` rows that tiles ``s``."""
+    if s <= target:
+        return s
+    b = target
+    while b >= SUBLANES:
+        if s % b == 0 and b % SUBLANES == 0:
+            return b
         b //= 2
-    return max(b, 1)
+    raise ValueError(f"cannot tile an extent of {s} with blocks of at most "
+                     f"{target} rows in multiples of {SUBLANES}")
 
 
 @partial(jax.jit, static_argnames=("causal", "interpret"))
@@ -48,6 +60,8 @@ def ssd_scan(xb, dt, a_neg, bmat, cmat, chunk: int, interpret: bool = False):
     ``repro.models.ssm.ssd_chunked_ref``. The final state (needed only by
     prefill) is reconstructed with one extra lightweight pass.
     """
+    if chunk < xb.shape[1] and chunk % SUBLANES:
+        raise ValueError(f"chunk {chunk} is off the {SUBLANES}-row tiling")
     xh = jnp.moveaxis(xb, 1, 2)   # (B,H,L,P)
     dth = jnp.moveaxis(dt, 1, 2)  # (B,H,L)
     y = ssd.ssd_scan_bhlp(xh, dth, a_neg, bmat, cmat, chunk,

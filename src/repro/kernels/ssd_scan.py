@@ -8,7 +8,12 @@ product (Q x P), and the state update (N x P) — on VMEM-resident tiles,
 so HBM traffic per chunk is the operand tiles only.
 
 Inputs are pre-scaled x̄ = x·dt and pre-activated B/C (the layer applies
-conv+SiLU before the scan). Decay math is fp32 in-kernel.
+conv+SiLU before the scan). The per-chunk running sum of the log-decays
+dt·A is taken in XLA before the call, because Mosaic has no ``cumsum``;
+the kernel reads it twice, as a (Q, 1) column and a (1, Q) row, so the
+(Q, Q) decay matrix is one broadcast subtraction with no transpose.
+Every block's last two dims are either the array's own or (chunk, full),
+which keeps the chunk axis on the (8, 128) tiling. Decay math is fp32.
 """
 
 from __future__ import annotations
@@ -21,7 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
+def _ssd_kernel(x_ref, col_ref, row_ref, b_ref, c_ref, y_ref, state_ref,
                 *, chunk: int):
     ci = pl.program_id(2)
 
@@ -30,16 +35,17 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0, 0].astype(jnp.float32)          # (Q, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)        # (Q,)
-    a_neg = a_ref[0]                              # () per-head A (negative)
+    cum = col_ref[0, 0, 0]                        # (Q, 1) inclusive, <= 0
+    cum_row = row_ref[0, 0, 0]                    # (1, Q) the same sums
+    # whole-chunk decay as a scalar: Mosaic cannot broadcast a (1, 1)
+    # slice taken at lane Q-1, but it can broadcast a reduction's result
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+    cum_end = jnp.sum(jnp.where(lane == chunk - 1, cum_row, 0.0))
     bm = b_ref[0, 0].astype(jnp.float32)          # (Q, N)
     cm = c_ref[0, 0].astype(jnp.float32)          # (Q, N)
 
-    loga = dt * a_neg                             # (Q,) <= 0
-    cum = jnp.cumsum(loga)                        # (Q,)
-
     # intra-chunk: (C B^T * decay) x
-    seg = cum[:, None] - cum[None, :]             # (Q, Q)
+    seg = cum - cum_row                           # (Q, Q) t,s
     rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     decay = jnp.where(rows >= cols, jnp.exp(seg), 0.0)
@@ -52,14 +58,14 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
     s_prev = state_ref[...]                       # (N, P)
     cs = jax.lax.dot_general(cm, s_prev, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    y_inter = jnp.exp(cum)[:, None] * cs
+    y_inter = jnp.exp(cum) * cs
 
     y_ref[0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # state update: S = exp(cum_end) * S_prev + sum_s w_s B_s x_s^T
-    w_end = jnp.exp(cum[-1] - cum)                # (Q,)
-    s_new = (jnp.exp(cum[-1]) * s_prev
-             + jax.lax.dot_general(bm * w_end[:, None], x,
+    w_end = jnp.exp(cum_end - cum)                # (Q, 1)
+    s_new = (jnp.exp(cum_end) * s_prev
+             + jax.lax.dot_general(bm * w_end, x,
                                    (((0,), (0,)), ((), ())),
                                    preferred_element_type=jnp.float32))
     state_ref[...] = s_new
@@ -75,8 +81,12 @@ def ssd_scan_bhlp(xb, dt, a_neg, bmat, cmat, chunk: int,
     b, h, l, p = xb.shape
     n = bmat.shape[-1]
     chunk = min(chunk, l)
-    assert l % chunk == 0, (l, chunk)
+    if l % chunk:
+        raise ValueError(f"chunk {chunk} does not divide length {l}")
     nc = l // chunk
+
+    loga = dt.astype(jnp.float32) * a_neg.astype(jnp.float32)[None, :, None]
+    cum = jnp.cumsum(loga.reshape(b, h, nc, chunk), axis=-1)
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
     return pl.pallas_call(
@@ -84,8 +94,10 @@ def ssd_scan_bhlp(xb, dt, a_neg, bmat, cmat, chunk: int,
         grid=(b, h, nc),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda bi, hi, ci: (bi, hi, ci)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            pl.BlockSpec((1, 1, 1, chunk, 1),
+                         lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, chunk),
+                         lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda bi, hi, ci: (bi, 0, ci, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda bi, hi, ci: (bi, 0, ci, 0)),
         ],
@@ -94,4 +106,4 @@ def ssd_scan_bhlp(xb, dt, a_neg, bmat, cmat, chunk: int,
         out_shape=jax.ShapeDtypeStruct((b, h, l, p), xb.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(xb, dt, a_neg, bmat[:, None], cmat[:, None])
+    )(xb, cum[..., None], cum[..., None, :], bmat[:, None], cmat[:, None])

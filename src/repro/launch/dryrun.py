@@ -1,9 +1,12 @@
 """Multi-pod dry-run: AOT lower + compile every (arch x shape x mesh) cell.
 
 MUST be the very first statements — before any other import (jax locks the
-device count at first init):
+device count and platform at first init). The fake devices are host
+devices, so the platform is pinned to the CPU: on a machine with a chip,
+a dry run (and every child of ``repro.launch.sweep``) never takes it.
 """
 import os  # noqa: E402
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))

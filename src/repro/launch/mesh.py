@@ -12,19 +12,11 @@ and ZeRO state, which tolerate the slower inter-pod DCN links.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types; older jax has Auto semantics only
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -34,8 +26,14 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
-    """Small mesh over however many (possibly fake) local devices exist."""
+    """A (data, model) mesh over the first ``data * model`` local devices.
+
+    Raises ``ValueError`` when fewer devices exist than the mesh asks
+    for: a silently smaller mesh would run a different program than the
+    one requested.
+    """
     n = len(jax.devices())
-    data = min(data, n)
-    model = min(model, max(1, n // data))
+    if data < 1 or model < 1 or data * model > n:
+        raise ValueError(f"mesh data={data} x model={model} needs "
+                         f"{data * model} devices; {n} available")
     return _make_mesh((data, model), ("data", "model"))

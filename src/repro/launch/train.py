@@ -1,11 +1,11 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
-On this container the launcher runs reduced configs on the host devices;
-on a real cluster the same entry point runs the full config on the
-production mesh (the dry-run proves those lower+compile). With
-``--predict``, DNNAbacus admission control estimates step time and peak
-memory for the requested config *before* allocating anything and refuses
-jobs predicted to OOM — the paper's scheduling application wired into the
+Runs the reduced smoke config by default and the published widths with
+``--full-size``, on the local devices: one, or a ``--data`` x
+``--model-par`` mesh. With ``--predict``, DNNAbacus admission control
+estimates step time and peak memory for the requested config *before*
+allocating anything and refuses jobs predicted to exceed the memory the
+devices report — the paper's scheduling application wired into the
 launcher.
 """
 
@@ -36,13 +36,17 @@ def main(argv=None) -> int:
     ap.add_argument("--log", default=None)
     args = ap.parse_args(argv)
 
+    import jax
+
     from repro.configs import get_config, reduced_config
+    from repro.launch.compile_cache import use_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.models import build_model
     from repro.train import optimizer as opt_lib
     from repro.train.loop import LoopConfig, Trainer
     from repro.distributed import sharding as shd
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if not args.full_size:
         cfg = reduced_config(cfg)
@@ -59,11 +63,18 @@ def main(argv=None) -> int:
             predicted = est["time_s"]
             print(f"[abacus] predicted step time {est['time_s']*1e3:.1f} ms, "
                   f"peak memory {est['memory_bytes']/2**30:.2f} GiB")
-            budget = est.get("hbm_budget", 34 * 2**30)
-            if est["memory_bytes"] > budget:
+            devices = (list(mesh.devices.flat) if mesh is not None
+                       else jax.devices()[:1])
+            limits = [(d.memory_stats() or {}).get("bytes_limit")
+                      for d in devices]
+            if None in limits:
+                print(f"[abacus] {devices[0].platform} reports no memory "
+                      "limit; admission not checked", file=sys.stderr)
+            elif est["memory_bytes"] > sum(limits):
                 print("[abacus] REFUSED: predicted OOM "
-                      f"({est['memory_bytes']/2**30:.1f} GiB > "
-                      f"{budget/2**30:.1f} GiB)", file=sys.stderr)
+                      f"({est['memory_bytes']/2**30:.2f} GiB > "
+                      f"{sum(limits)/2**30:.2f} GiB on {len(devices)} "
+                      f"{devices[0].device_kind})", file=sys.stderr)
                 return 2
         else:
             print("[abacus] no fitted predictor found; run "

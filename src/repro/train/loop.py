@@ -60,7 +60,11 @@ class Trainer:
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                 self.source.batch_at(0))
             self.batch_sh = step_lib.batch_shardings(mesh, sample, self.rules)
-            self.jstep = jax.jit(step_fn, in_shardings=(self.state_sh, self.batch_sh),
+            # the returned state must keep the layout the step takes, or
+            # the next call sees arguments that no longer match
+            self.jstep = jax.jit(step_fn,
+                                 in_shardings=(self.state_sh, self.batch_sh),
+                                 out_shardings=(self.state_sh, None),
                                  donate_argnums=(0,))
         else:
             self.state_sh = None

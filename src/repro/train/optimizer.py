@@ -56,7 +56,10 @@ def init_opt_state(cfg: OptConfig, params) -> Any:
         "count": jnp.zeros((), jnp.int32),
     }
     if cfg.keep_master and _needs_master(params):
-        state["master"] = jax.tree.map(lambda p: p.astype(jnp.float32), params)
+        # a copy even of params that are already fp32: the train step
+        # donates the whole state, and no buffer may be donated twice
+        state["master"] = jax.tree.map(
+            lambda p: jnp.array(p, jnp.float32, copy=True), params)
     else:
         state["master"] = {}
     return state

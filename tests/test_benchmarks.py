@@ -72,6 +72,35 @@ def test_collect_appends_new_records_to_cache(tmp_path, monkeypatch):
     assert again[0].model_name == "nin"
 
 
+def test_collect_keys_records_by_device(tmp_path, monkeypatch):
+    """A record measured on another device is never served as this one's."""
+    cache = tmp_path / "profiles.jsonl"
+    combo = {"kind": "zoo", "name": "nin", "batch": 8, "image": 32}
+    other = dict(json.loads(collect._key(combo)), platform="tpu",
+                 device_kind="TPU v5 lite")
+    cache.write_text(json.dumps({"key": json.dumps(other, sort_keys=True),
+                                 "record": record_to_json(_record("nin"))})
+                     + "\n")
+    monkeypatch.setattr(collect, "CACHE", str(cache))
+    assert collect.all_cached() == []
+    monkeypatch.setattr(collect, "_profile", lambda c: _record("fresh"))
+    recs = collect.collect([combo], verbose=False)
+    assert recs[0].model_name == "fresh"  # profiled here, not the tpu label
+    assert [r.model_name for r in collect.all_cached()] == ["fresh"]
+
+
+def test_collect_fails_on_a_failed_profile(tmp_path, monkeypatch):
+    monkeypatch.setattr(collect, "CACHE", str(tmp_path / "profiles.jsonl"))
+
+    def boom(combo):
+        raise RuntimeError("compile refused")
+
+    monkeypatch.setattr(collect, "_profile", boom)
+    with pytest.raises(RuntimeError, match="compile refused") as info:
+        collect.collect([{"kind": "zoo", "name": "nin"}], verbose=False)
+    assert any("nin" in note for note in info.value.__notes__)
+
+
 # -- run.py -------------------------------------------------------------------
 
 

@@ -90,6 +90,51 @@ def test_runner_gives_up_after_max_retries():
         runner(1)
 
 
+def test_runner_keeps_first_error_as_cause():
+    inj = FailureInjector(fail_on_calls=(1, 2, 3))
+    runner = StepRunner(inj.wrap(lambda x: x), FTConfig(max_retries=2))
+    with pytest.raises(StepFailure) as info:
+        runner(1)
+    assert "call 1" in str(info.value.__cause__)
+    assert inj.calls == 3 and runner.retries == 2
+
+
+@pytest.mark.parametrize("error", [
+    TypeError("unexpected argument"),
+    ValueError("sharding mismatch"),
+    RuntimeError("INVALID_ARGUMENT: Attempt to donate the same buffer twice"),
+])
+def test_runner_does_not_retry_argument_errors(error):
+    calls = []
+
+    def step(x):
+        calls.append(x)
+        raise error
+
+    runner = StepRunner(step, FTConfig(max_retries=2))
+    with pytest.raises(StepFailure, match="cannot be retried") as info:
+        runner(1)
+    assert info.value.__cause__ is error
+    assert len(calls) == 1 and runner.retries == 0
+
+
+def test_runner_does_not_retry_after_donation():
+    """A call that failed after consuming its donated state cannot rerun."""
+    donate = jax.jit(lambda s: s + 1, donate_argnums=0)
+    calls = []
+
+    def step(state):
+        calls.append(1)
+        donate(state)  # the step's buffers are gone ...
+        raise RuntimeError("device lost")  # ... when the device fails
+
+    runner = StepRunner(step, FTConfig(max_retries=2))
+    with pytest.raises(StepFailure, match="cannot be retried") as info:
+        runner(jnp.ones(4))
+    assert "device lost" in str(info.value.__cause__)
+    assert len(calls) == 1 and runner.retries == 0
+
+
 def test_straggler_detection_with_prediction():
     import time
     flags = []
@@ -104,6 +149,19 @@ def test_straggler_detection_with_prediction():
     runner(1)   # slow step -> flagged
     runner(1)
     assert runner.stragglers >= 1 and len(flags) >= 1
+
+
+def test_master_weights_share_no_buffer_with_params():
+    """The step donates params and master together; XLA refuses one buffer
+    donated twice, so an fp32 param's master must be its own copy."""
+    from repro.train import optimizer as opt_lib
+    params = {"w": jnp.ones((4, 4), jnp.bfloat16),
+              "scale": jnp.ones((4,), jnp.float32)}
+    state = opt_lib.init_opt_state(opt_lib.OptConfig(), params)
+    for name, p in params.items():
+        master = state["master"][name]
+        assert master.dtype == jnp.float32
+        assert master.unsafe_buffer_pointer() != p.unsafe_buffer_pointer()
 
 
 def test_trainer_resumes_after_simulated_crash(tmp_path):

@@ -7,7 +7,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.distributed.compression import (allreduce_compressed,
                                            ef_compress, ef_decompress,
-                                           ef_init, shard_map)
+                                           ef_init)
 from repro.launch.mesh import make_host_mesh
 
 
@@ -45,7 +45,7 @@ def test_allreduce_compressed_single_device():
         return allreduce_compressed(g, ef, "data")
 
     out, ef2 = jax.jit(
-        shard_map(f, mesh=mesh,
-                  in_specs=(P(), P()), out_specs=(P(), P())))(g, ef)
+        jax.shard_map(f, mesh=mesh,
+                      in_specs=(P(), P()), out_specs=(P(), P())))(g, ef)
     rel = float(jnp.linalg.norm(out["w"] - g["w"]) / jnp.linalg.norm(g["w"]))
     assert rel < 0.02

@@ -88,6 +88,16 @@ def test_rmsnorm_allclose(r, d, dtype):
                                atol=tol, rtol=tol)
 
 
+def test_untileable_shapes_raise():
+    """No fallback: a shape off the TPU tiling is refused, not shrunk."""
+    with pytest.raises(ValueError, match="tile"):
+        ops.rmsnorm(jnp.ones((300, 64)), jnp.ones((64,)))
+    with pytest.raises(ValueError, match="chunk"):
+        ops.ssd_scan(jnp.ones((1, 96, 1, 8)), jnp.ones((1, 96, 1)),
+                     -jnp.ones((1,)), jnp.ones((1, 96, 4)),
+                     jnp.ones((1, 96, 4)), chunk=20)
+
+
 def test_model_level_pallas_path_matches_xla():
     """StackModel forward with pallas-interpret attention == XLA path."""
     from repro.configs import get_config, reduced_config

@@ -1,0 +1,101 @@
+"""Compiles for a described TPU v5e, at published widths, without a chip.
+
+The TPU compiler refuses what interpret mode accepts: blocks off the
+(8, 128) tiling, primitives Mosaic cannot lower, programs that do not fit
+the chip's memory. Each test compiles one program of the chip path for
+one v5e chip and checks what the compiler made of it. Nothing runs.
+
+The topology is described inside a module fixture, never at import, so
+every xdist worker collects the same tests and only the worker that runs
+this file loads the TPU library.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import ops
+
+# usable HBM of one v5e chip, as its compiler reports it ("of 15.75G")
+V5E_HBM_BYTES = 15.75 * 2**30
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_flash_attention_qwen2_widths(one_chip):
+    cfg = get_config("qwen2-0.5b")
+    shape = (8, 1024, cfg.num_heads, cfg.resolved_head_dim)
+    q = _on(one_chip, shape, jnp.bfloat16)
+    compiled = ops.flash_attention.lower(q, q, q, causal=True).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "mamba2-370m"])
+def test_rmsnorm_published_widths(one_chip, arch):
+    d = get_config(arch).d_model
+    x = _on(one_chip, (8 * 1024, d), jnp.bfloat16)
+    g = _on(one_chip, (d,), jnp.float32)
+    compiled = ops.rmsnorm.lower(x, g).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssd_scan_mamba2_widths(one_chip):
+    cfg = get_config("mamba2-370m")
+    b, l, h, p, n = 4, 1024, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    assert (h, p, n, cfg.ssm_chunk) == (32, 64, 128, 256)
+    compiled = ops.ssd_scan.lower(
+        _on(one_chip, (b, l, h, p), jnp.bfloat16),
+        _on(one_chip, (b, l, h), jnp.float32),
+        _on(one_chip, (h,), jnp.float32),
+        _on(one_chip, (b, l, n), jnp.bfloat16),
+        _on(one_chip, (b, l, n), jnp.bfloat16),
+        chunk=cfg.ssm_chunk).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_launcher_step_qwen2_fits_one_chip(one_chip):
+    """The launcher's donated full-width step at the chip smoke's shape."""
+    from repro.models import build_model
+    from repro.train import optimizer as opt_lib
+    from repro.train import step as step_lib
+    from repro.train.loop import LoopConfig, Trainer
+
+    model = build_model(get_config("qwen2-0.5b"))
+    opt_cfg = opt_lib.OptConfig()
+    trainer = Trainer(model, opt_cfg, LoopConfig(batch=8, seq=1024))
+    on_chip = lambda t: jax.tree.map(
+        lambda s: _on(one_chip, s.shape, s.dtype), t)
+    state = on_chip(step_lib.state_shapes(model, opt_cfg))
+    batch = on_chip({"tokens": jax.ShapeDtypeStruct((8, 1024), jnp.int32),
+                     "labels": jax.ShapeDtypeStruct((8, 1024), jnp.int32)})
+    compiled = trainer.jstep.lower(state, batch).compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes > 0.9 * ma.argument_size_in_bytes  # donated
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert total < V5E_HBM_BYTES, total / 2**30
