@@ -67,7 +67,12 @@ class MemmapLM:
 
 
 class ShardedLoader:
-    """Places host batches on the mesh, with background prefetch."""
+    """Places host batches on the mesh, with background prefetch.
+
+    Traced on the profiler's clock: ``loader/produce`` (``batch_at`` and
+    the placement, on the producer thread) and ``loader/wait`` (the
+    consumer blocked on the prefetch queue).
+    """
 
     def __init__(self, source, shardings=None, start_step: int = 0,
                  prefetch: int = 2):
@@ -88,15 +93,19 @@ class ShardedLoader:
     def _producer(self):
         step = self.step
         while not self._stop.is_set():
-            b = self.source.batch_at(step)
-            try:
-                self._q.put((step, self._put(b)), timeout=0.5)
-                step += 1
-            except queue.Full:
-                continue
+            with jax.profiler.TraceAnnotation("loader/produce"):
+                item = (step, self._put(self.source.batch_at(step)))
+            while not self._stop.is_set():
+                try:
+                    self._q.put(item, timeout=0.5)
+                    break
+                except queue.Full:
+                    continue
+            step += 1
 
     def __next__(self):
-        step, batch = self._q.get()
+        with jax.profiler.TraceAnnotation("loader/wait"):
+            step, batch = self._q.get()
         self.step = step + 1
         return batch
 

@@ -93,7 +93,8 @@ def main(argv=None) -> int:
         trainer.write_log(args.log)
     print(f"final loss: {log[-1]['loss']:.4f} "
           f"(retries={trainer.runner.retries}, "
-          f"stragglers={trainer.runner.stragglers})")
+          f"stragglers={trainer.runner.stragglers}, "
+          f"compiles={trainer.runner.compiles})")
     return 0
 
 

@@ -178,6 +178,7 @@ def _constrain(shard, name, x):
     return jax.lax.with_sharding_constraint(x, s) if s is not None else x
 
 
+@jax.named_scope("attention")
 def apply_self_attn(p, cfg, x, positions, shard=None, causal=True):
     """Full-sequence self-attention. Returns (y, (k_cache, v_cache))."""
     q = _project_q(p, cfg, x)
@@ -205,6 +206,7 @@ def apply_self_attn(p, cfg, x, positions, shard=None, causal=True):
     return _out_proj(p, ctx, x.dtype), (kc, vc)
 
 
+@jax.named_scope("attention")
 def apply_cross_attn(p, cfg, x, memory, shard=None):
     """Cross-attention to (B,M,D) memory (no mask, no RoPE)."""
     q = _constrain(shard, "acts_qkv", _project_q(p, cfg, x))
@@ -213,6 +215,7 @@ def apply_cross_attn(p, cfg, x, memory, shard=None):
     return _out_proj(p, ctx, x.dtype), (k, v)
 
 
+@jax.named_scope("attention")
 def decode_self_attn(p, cfg, x_t, cache, pos, shard=None):
     """One-token decode. x_t (B,1,D); cache {"k","v"} (B,Smax,KV,hd);
     pos (B,) positions (attention masks per-request).
@@ -259,6 +262,7 @@ def decode_self_attn(p, cfg, x_t, cache, pos, shard=None):
     return _out_proj(p, ctx, x_t.dtype), {"k": k, "v": v}
 
 
+@jax.named_scope("attention")
 def decode_cross_attn(p, cfg, x_t, cache):
     q = _project_q(p, cfg, x_t)
     ctx = dot_attention(q, _expand_kv(cfg, cache["k"].astype(q.dtype)),

@@ -2,7 +2,10 @@
 
 All apply functions take the params subtree produced from the matching
 ``*_spec`` function. Compute runs in the activation dtype; reductions that
-need it (norm statistics, softmax, loss) run in fp32.
+need it (norm statistics, softmax, loss) run in fp32. Each layer runs
+under a ``jax.named_scope`` (``norm``, ``mlp``, ``embed``, ``lm_head``;
+``attention``, ``moe`` and ``ssm`` in their modules), which names its ops
+in a profiler trace and changes nothing else.
 """
 
 from __future__ import annotations
@@ -27,6 +30,7 @@ def norm_spec(cfg, d=None):
     return {"scale": spec((d,), ("embed",), "ones", dtype=jnp.float32)}
 
 
+@jax.named_scope("norm")
 def apply_norm(p, x, eps: float = 1e-6):
     dt = x.dtype
     xf = x.astype(jnp.float32)
@@ -86,6 +90,7 @@ def mlp_spec(cfg, d_ff=None):
     }
 
 
+@jax.named_scope("mlp")
 def apply_mlp(p, x):
     gu = jnp.einsum("...d,dgf->...gf", x, p["wi"].astype(x.dtype))
     g, u = gu[..., 0, :], gu[..., 1, :]
@@ -117,10 +122,12 @@ def embed_spec(cfg):
     }
 
 
+@jax.named_scope("embed")
 def embed_tokens(p, tokens, dtype):
     return jnp.take(p["table"], tokens, axis=0).astype(dtype)
 
 
+@jax.named_scope("lm_head")
 def unembed(p, x, softcap: float = 0.0, vocab: int = 0):
     w = p["table"].T if "unembed" not in p else p["unembed"]
     logits = jnp.einsum("...d,dv->...v", x, w.astype(x.dtype)).astype(jnp.float32)
@@ -133,6 +140,7 @@ def unembed(p, x, softcap: float = 0.0, vocab: int = 0):
     return logits
 
 
+@jax.named_scope("lm_head")
 def cross_entropy(logits, labels, mask=None):
     """Mean token cross-entropy in fp32. logits (..., V), labels (...) int."""
     lse = jax.nn.logsumexp(logits, axis=-1)

@@ -40,6 +40,7 @@ def _constrain(shard, name, x):
     return jax.lax.with_sharding_constraint(x, sh) if sh is not None else x
 
 
+@jax.named_scope("moe")
 def apply_moe(p, cfg, x, shard=None):
     """x (B,S,D) -> (y (B,S,D), aux_loss scalar fp32)."""
     b, s, d = x.shape

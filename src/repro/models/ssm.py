@@ -138,6 +138,7 @@ def ssd_chunked_ref(xb, dt, a_neg, bmat, cmat, chunk: int):
     return y, s_final
 
 
+@jax.named_scope("ssm")
 def apply_ssm(p, cfg, x, return_cache: bool = False):
     """Full-sequence Mamba2 block. x (B,L,D) -> (y (B,L,D), cache_or_state)."""
     b, l, d = x.shape
@@ -183,6 +184,7 @@ def _gated_norm(y, z, scale, eps: float = 1e-6):
     return (yf * jax.lax.rsqrt(ms + eps) * scale).astype(y.dtype)
 
 
+@jax.named_scope("ssm")
 def decode_ssm(p, cfg, x_t, cache):
     """Single-token Mamba2 step. x_t (B,1,D); cache {"state","conv_*"}."""
     b = x_t.shape[0]

@@ -4,6 +4,8 @@ The loop is deliberately host-driven and restartable: all state lives in
 the (atomic) checkpoint, the data stream is deterministic in step, and
 the mesh shape may change between runs (elastic restart) because restores
 re-shard. ``run()`` returns the metrics history for tests/benchmarks.
+Each step is a ``StepTraceAnnotation("train")`` for the profiler's step
+view, and checkpoint writes are ``ckpt/save`` spans.
 """
 
 from __future__ import annotations
@@ -11,7 +13,6 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import time
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -98,22 +99,25 @@ class Trainer:
         loader = ShardedLoader(self.source, self.batch_sh, start_step=start)
         try:
             for i in range(start, steps):
-                batch = next(loader)
-                t0 = time.perf_counter()
-                state, metrics = self.runner(state, batch)
-                dt = time.perf_counter() - t0
-                if i % self.cfg.log_every == 0 or i == steps - 1:
-                    rec = {k: float(v) for k, v in metrics.items()}
-                    rec.update(step=i, step_time_s=dt)
-                    self.metrics_log.append(rec)
-                if (self.cfg.ckpt_dir and self.cfg.ckpt_every
-                        and (i + 1) % self.cfg.ckpt_every == 0):
-                    ckpt_lib.save(self.cfg.ckpt_dir, i + 1, state)
+                with jax.profiler.StepTraceAnnotation("train", step_num=i):
+                    batch = next(loader)
+                    state, metrics = self.runner(state, batch)
+                    if i % self.cfg.log_every == 0 or i == steps - 1:
+                        rec = {k: float(v) for k, v in metrics.items()}
+                        rec.update(step=i, step_time_s=self.runner.history[-1])
+                        self.metrics_log.append(rec)
+                    if (self.cfg.ckpt_dir and self.cfg.ckpt_every
+                            and (i + 1) % self.cfg.ckpt_every == 0):
+                        self._save(i + 1, state)
             if self.cfg.ckpt_dir:
-                ckpt_lib.save(self.cfg.ckpt_dir, steps, state)
+                self._save(steps, state)
         finally:
             loader.close()
         return self.metrics_log
+
+    def _save(self, step: int, state) -> None:
+        with jax.profiler.TraceAnnotation("ckpt/save"):
+            ckpt_lib.save(self.cfg.ckpt_dir, step, state)
 
     def write_log(self, path: str) -> None:
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)

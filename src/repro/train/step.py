@@ -53,8 +53,9 @@ def make_train_step(model, opt_cfg: opt_lib.OptConfig, accum: int = 1):
 
     def train_step(state: Dict[str, Any], batch: Dict[str, jax.Array]):
         loss, metrics, grads = compute_grads(state["params"], batch)
-        new_params, new_opt, opt_metrics = opt_lib.apply_updates(
-            opt_cfg, state["params"], grads, state["opt"])
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, opt_metrics = opt_lib.apply_updates(
+                opt_cfg, state["params"], grads, state["opt"])
         metrics = dict(metrics)
         metrics.update(opt_metrics)
         new_state = {"params": new_params, "opt": new_opt,
