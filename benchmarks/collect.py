@@ -3,9 +3,9 @@
 The paper collects 17,300 + 5,500 data points across days of GPU time; on
 one host the benchmark suite collects a scaled-down set (a few hundred
 points; BENCH_FULL=1 widens the grid) once, cached in
-``artifacts/profiles.jsonl`` keyed by configuration and by the device it
-was measured on, so every MRE benchmark reads the same corpus and never
-another device's labels. A profile that fails stops the collection.
+``artifacts/profiles.jsonl`` keyed by configuration, by the device it
+was measured on and by the attention path its steps took there, so every
+MRE benchmark reads the same corpus and never another device's labels. A profile that fails stops the collection.
 """
 
 from __future__ import annotations
@@ -92,10 +92,15 @@ def lm_grid() -> List[Dict]:
 
 
 def _device() -> Dict[str, str]:
-    """The device this process measures on, as JAX reports it."""
+    """The device this process measures on, as JAX reports it, and the
+    attention path its steps take there (a label profiled through the
+    materialised scores is never mixed with the kernel's)."""
     import jax
+
+    from repro.models.attention import attention_key
     d = jax.devices()[0]
-    return {"platform": d.platform, "device_kind": d.device_kind}
+    return {"platform": d.platform, "device_kind": d.device_kind,
+            "attention": attention_key()}
 
 
 def _key(combo: Dict) -> str:

@@ -116,10 +116,14 @@ def check_kernels(attn_cfg, ssd_cfg, batch: int, seq: int,
     bf16_out = ("bf16 output: rounding is 2^-9 relative, and f32 operands "
                 "may enter the MXU as bf16; 1e-2 is a few times both")
 
-    h, hd = attn_cfg.num_heads, attn_cfg.resolved_head_dim
-    q, k, v = (rand((batch, seq, h, hd), jnp.bfloat16) for _ in range(3))
+    h, kv, hd = (attn_cfg.num_heads, attn_cfg.num_kv_heads,
+                 attn_cfg.resolved_head_dim)
+    q = rand((batch, seq, h, hd), jnp.bfloat16)
+    k, v = (rand((batch, seq, kv, hd), jnp.bfloat16) for _ in range(2))
     got = ops.flash_attention(q, k, v, causal=True, interpret=interpret)
-    flat = lambda t: f32(jnp.moveaxis(t, 2, 1).reshape(batch * h, seq, hd))
+    # the reference takes every query head's own copy of its group's K/V
+    flat = lambda t: f32(jnp.moveaxis(jnp.repeat(t, h // t.shape[2], axis=2),
+                                      2, 1).reshape(batch * h, seq, hd))
     with jax.default_matmul_precision("highest"):
         want = ref.attention_ref(flat(q), flat(k), flat(v), causal=True)
     want = jnp.moveaxis(want.reshape(batch, h, seq, hd), 1, 2)

@@ -25,6 +25,7 @@ from repro.analysis import hlo as hlo_lib
 from repro.core import nsm as nsm_lib
 from repro.core.features import ProfileRecord
 from repro.core.zoo import ZooModel, build_zoo_model
+from repro.models.attention import attention_impl
 
 # ---------------------------------------------------------------------------
 # Tiny optimizers for the profiling rig (the paper varies the optimizer)
@@ -83,13 +84,24 @@ def _softmax_ce(logits, labels):
 
 def profile_step(step_fn, args, steps: int = 3,
                  donate: Tuple[int, ...] = ()) -> Dict:
-    """Compile & run ``step_fn(*args)``; return measurements + features."""
-    closed = jax.make_jaxpr(step_fn)(*args)
-    edges = nsm_lib.nsm_edges(closed)
-    jf = jax.jit(step_fn, donate_argnums=donate)
-    lowered = jf.lower(*args)
-    compiled = lowered.compile()
-    cost = hlo_lib.analyze_text(compiled.as_text())
+    """Compile & run ``step_fn(*args)``; return measurements + features.
+
+    Time and memory are the step's as it runs. The features (NSM edges,
+    FLOPs) are read with attention pinned to XLA, so they do not depend on
+    which kernel the platform runs: a Pallas call would replace the
+    attention equations in the jaxpr and hide its dots from the HLO text.
+    """
+    traced = jax.jit(step_fn, donate_argnums=donate).trace(*args)
+    compiled = traced.lower().compile()
+    features = compiled
+    if "pallas_call" in str(traced.jaxpr):
+        with attention_impl("xla"):
+            # a new function: JAX caches a trace by function, not by the pin
+            traced = jax.jit(lambda *a: step_fn(*a),
+                             donate_argnums=donate).trace(*args)
+        features = traced.lower().compile()
+    edges = nsm_lib.nsm_edges(traced.jaxpr)
+    cost = hlo_lib.analyze_text(features.as_text())
     ma = compiled.memory_analysis()
     mem = (getattr(ma, "argument_size_in_bytes", 0)
            + getattr(ma, "output_size_in_bytes", 0)

@@ -37,19 +37,52 @@ def _pick_block(s: int, target: int) -> int:
                      f"{target} rows in multiples of {SUBLANES}")
 
 
-@partial(jax.jit, static_argnames=("causal", "interpret"))
-def flash_attention(q, k, v, causal: bool = True, interpret: bool = False):
-    """Model layout: q (B,S,H,hd), k/v (B,S,H,hd) (pre-expanded GQA)."""
-    b, sq, h, hd = q.shape
-    sk = k.shape[1]
-    qf = jnp.moveaxis(q, 2, 1).reshape(b * h, sq, hd)
-    kf = jnp.moveaxis(k, 2, 1).reshape(b * h, sk, hd)
-    vf = jnp.moveaxis(v, 2, 1).reshape(b * h, sk, hd)
-    bq = _pick_block(sq, 128)
-    bk = _pick_block(sk, 128)
-    o = fa.flash_attention_bhsd(qf, kf, vf, causal=causal, block_q=bq,
-                                block_k=bk, interpret=interpret)
-    return jnp.moveaxis(o.reshape(b, h, sq, hd), 1, 2)
+# (seq, head_dim) -> (block_q, block_k): the fastest forward + backward of
+# a sweep on a TPU v5e chip (PERF.md); other shapes take the
+# largest square block of at most DEFAULT_BLOCK rows that tiles them
+FLASH_BLOCKS = {(512, 64): (512, 512), (1024, 64): (1024, 1024),
+                (2048, 64): (2048, 2048), (4096, 64): (2048, 2048)}
+DEFAULT_BLOCK = 1024
+# the head widths the kernel is built and tested for
+FLASH_HEAD_DIMS = (64, 128)
+# a block of a row vector (the log-sum-exp) is a multiple of the lane
+# width unless it spans the whole extent
+LANES = fa.LANES
+
+
+def flash_blocks(seq: int, head_dim: int):
+    """(block_q, block_k) for a square ``seq`` x ``seq`` attention."""
+    if (seq, head_dim) in FLASH_BLOCKS:
+        return FLASH_BLOCKS[seq, head_dim]
+    b = _pick_block(seq, DEFAULT_BLOCK)
+    if b != seq and b % LANES:
+        raise ValueError(f"cannot tile an extent of {seq} with blocks of at "
+                         f"most {DEFAULT_BLOCK} rows in multiples of {LANES}")
+    return b, b
+
+
+@partial(jax.jit, static_argnames=("causal", "blocks", "interpret"))
+def flash_attention_bhsd(q, k, v, causal: bool = True, blocks=None,
+                         interpret: bool = False):
+    """Heads leading: q (B,H,S,hd), k/v (B,KV,S,hd) with KV dividing H.
+
+    ``blocks`` is (block_q, block_k); by default ``flash_blocks``'s.
+    Differentiable: the backward pass is the kernels' own.
+    """
+    sq, sk, hd = q.shape[2], k.shape[2], q.shape[-1]
+    if blocks is None:
+        blocks = flash_blocks(sq, hd)[0], flash_blocks(sk, hd)[1]
+    qs = q * jnp.asarray(hd ** -0.5, q.dtype)
+    return fa.flash_attention_bhsd(qs, k, v, causal, *blocks, interpret)
+
+
+@partial(jax.jit, static_argnames=("causal", "blocks", "interpret"))
+def flash_attention(q, k, v, causal: bool = True, blocks=None,
+                    interpret: bool = False):
+    """Model layout: q (B,S,H,hd), k/v (B,S,KV,hd); see ``flash_attention_bhsd``."""
+    heads = lambda t: jnp.swapaxes(t, 1, 2)
+    return heads(flash_attention_bhsd(heads(q), heads(k), heads(v), causal,
+                                      blocks, interpret))
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))

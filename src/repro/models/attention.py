@@ -6,35 +6,71 @@ because the flattened fan-out is divisible by the 16-way ``model`` axis
 for every assigned architecture, while raw head counts (40, 24, 56, 14,
 6…) are not, and jit ``in_shardings`` require even division. Activations
 are reshaped to (B,S,H,hd) and head-sharded via *constraints*, where GSPMD
-tolerates uneven (padded) sharding. GQA K/V are broadcast to the full head
-count at compute time (the Megatron convention when tp > kv_heads); the
-cache stores only the KV heads.
+tolerates uneven (padded) sharding. On the XLA path GQA K/V are broadcast
+to the full head count at compute time (the Megatron convention when
+tp > kv_heads); the kernel and the cache take only the KV heads.
 
 The full-sequence causal path runs through either the XLA einsum
-implementation (default — what the dry-run lowers and ``cost_analysis``
-meters) or the Pallas flash-attention kernel
-(``set_attention_impl("pallas")``; TPU target, interpret-mode on CPU).
+implementation or the Pallas flash-attention kernel, which has its own
+backward pass. ``causal_attention`` chooses from what it can observe
+(``_flash_blocks``): the kernel on a TPU, unsharded, for square attention
+of a head width the kernel supports, from ``FLASH_MIN_SEQ`` tokens; XLA
+otherwise, so CPU runs keep the einsum path. ``attention_impl("xla")``
+(or ``"pallas"``, ``"pallas_interpret"`` for tests) pins the choice while
+a function is traced.
 """
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.models.module import spec
+from repro.obs import events
+from repro.obs.metrics import MetricsRegistry
 
-_IMPL = "xla"
+_IMPL = "auto"
+IMPLS = ("auto", "xla", "pallas", "pallas_interpret")
+# The shortest sequence at which the kernel made the whole train step
+# faster on a TPU v5e (PERF.md, timing table)
+FLASH_MIN_SEQ = 1024
+# traces of the causal path by implementation (``flash``, ``xla``)
+TRACES = MetricsRegistry(namespace="attention")
 
 
 def set_attention_impl(impl: str) -> None:
     global _IMPL
-    assert impl in ("xla", "pallas", "pallas_interpret"), impl
+    if impl not in IMPLS:
+        raise ValueError(f"attention implementation {impl!r} not in {IMPLS}")
     _IMPL = impl
 
 
 def get_attention_impl() -> str:
+    return _IMPL
+
+
+@contextlib.contextmanager
+def attention_impl(impl: str):
+    """Pin the implementation inside the block. JAX caches a trace by
+    function, so trace a function made inside the block."""
+    was = _IMPL
+    set_attention_impl(impl)
+    try:
+        yield
+    finally:
+        set_attention_impl(was)
+
+
+def attention_key() -> str:
+    """Which path full-sequence causal attention takes, as a cache of
+    profiles keys it: ``xla``, ``flash>=<FLASH_MIN_SEQ>`` or the pinned
+    kernel setting."""
+    if _IMPL == "auto":
+        return (f"flash>={FLASH_MIN_SEQ}" if jax.default_backend() == "tpu"
+                else "xla")
     return _IMPL
 
 
@@ -131,16 +167,63 @@ CHUNK_THRESHOLD = 4096
 CHUNK_Q = 512
 
 
-def causal_attention(q, k, v):
-    if _IMPL in ("pallas", "pallas_interpret"):
+def _xla_kv(cfg, q, k, v, shard):
+    """K/V expanded to q's heads and laid out for the XLA path."""
+    sq = q.shape[1]
+    k, v = _expand_kv(cfg, k), _expand_kv(cfg, v)
+    if sq > CHUNK_THRESHOLD and sq % CHUNK_Q == 0 and shard is not None:
+        # hoist K/V to a replicated-over-model layout BEFORE the q-chunk
+        # scan: one all-gather per layer instead of one per chunk
+        return (_constrain(shard, "acts_kv_repl", k),
+                _constrain(shard, "acts_kv_repl", v))
+    return _constrain(shard, "acts_qkv", k), _constrain(shard, "acts_qkv", v)
+
+
+def _flash_blocks(q, k, shard):
+    """The kernel's (block_q, block_k) if this call takes it, else None."""
+    if _IMPL == "xla":
+        return None
+    from repro.kernels import ops as kops
+    sq, sk, hd = q.shape[1], k.shape[1], q.shape[-1]
+    if _IMPL == "auto" and (
+            jax.default_backend() != "tpu" or shard is not None or sq != sk
+            or sq < FLASH_MIN_SEQ or sq % kops.LANES
+            or hd not in kops.FLASH_HEAD_DIMS):
+        return None
+    # a pinned kernel takes every shape, and ops refuses one it cannot tile
+    return kops.flash_blocks(sq, hd)
+
+
+def causal_attention(cfg, q, k, v, positions, shard=None):
+    """q (B,S,H,hd), k/v (B,S,KV,hd) with KV dividing H, before RoPE ->
+    the context (B,S,H,hd) and the rotated k (the cache's)."""
+    blocks = _flash_blocks(q, k, shard)
+    impl = "xla" if blocks is None else "flash"
+    TRACES.counter(impl, f"causal attention traced on the {impl} path").inc()
+    events.emit("attention_impl", impl=impl, seq=q.shape[1],
+                head_dim=q.shape[-1], blocks=blocks)
+    if blocks is not None:
         from repro.kernels import ops as kops
-        return kops.flash_attention(
-            q, k, v, causal=True, interpret=(_IMPL == "pallas_interpret"))
+        heads = lambda t: jnp.swapaxes(t, 1, 2)
+        # heads lead before RoPE, so that XLA writes the rotated q and k in
+        # the kernel's layout in one fusion (after it, the rotation's
+        # halves are copied to that layout one by one)
+        rope = lambda t: _apply_rope(cfg, heads(t)[..., None, :],
+                                     positions[..., None, :])[..., 0, :]
+        qh, kh = rope(q), rope(k)
+        with jax.named_scope("flash"):
+            ctx = kops.flash_attention_bhsd(
+                qh, kh, heads(v), causal=True, blocks=blocks,
+                interpret=(_IMPL == "pallas_interpret"))
+        return heads(ctx), heads(kh)
+    q = _constrain(shard, "acts_qkv", _apply_rope(cfg, q, positions))
+    k = _apply_rope(cfg, k, positions)
+    kf, vf = _xla_kv(cfg, q, k, v, shard)
     sq, sk = q.shape[1], k.shape[1]
     if sq > CHUNK_THRESHOLD and sq % CHUNK_Q == 0:
-        return _chunked_causal_attention(q, k, v, CHUNK_Q)
+        return _chunked_causal_attention(q, kf, vf, CHUNK_Q), k
     mask = (jnp.arange(sk)[None, :] <= jnp.arange(sq)[:, None])[None, None]
-    return dot_attention(q, k, v, mask)
+    return dot_attention(q, kf, vf, mask), k
 
 
 def _chunked_causal_attention(q, k, v, block_q: int):
@@ -183,27 +266,14 @@ def apply_self_attn(p, cfg, x, positions, shard=None, causal=True):
     """Full-sequence self-attention. Returns (y, (k_cache, v_cache))."""
     q = _project_q(p, cfg, x)
     k, v = _project_kv(p, cfg, x, x.dtype)
-    q = _apply_rope(cfg, q, positions)
-    k = _apply_rope(cfg, k, positions)
-    kc, vc = k, v
-    q = _constrain(shard, "acts_qkv", q)
-    kf = _expand_kv(cfg, k)
-    vf = _expand_kv(cfg, v)
-    sq = q.shape[1]
-    if sq > CHUNK_THRESHOLD and sq % CHUNK_Q == 0 and shard is not None:
-        # hoist K/V to a replicated-over-model layout BEFORE the q-chunk
-        # scan: one all-gather per layer instead of one per chunk
-        kf = _constrain(shard, "acts_kv_repl", kf)
-        vf = _constrain(shard, "acts_kv_repl", vf)
-    else:
-        kf = _constrain(shard, "acts_qkv", kf)
-        vf = _constrain(shard, "acts_qkv", vf)
     if causal:
-        ctx = causal_attention(q, kf, vf)
+        ctx, k = causal_attention(cfg, q, k, v, positions, shard)
     else:
-        ctx = dot_attention(q, kf, vf)
+        q = _constrain(shard, "acts_qkv", _apply_rope(cfg, q, positions))
+        k = _apply_rope(cfg, k, positions)
+        ctx = dot_attention(q, *_xla_kv(cfg, q, k, v, shard))
     ctx = _constrain(shard, "acts_qkv", ctx)
-    return _out_proj(p, ctx, x.dtype), (kc, vc)
+    return _out_proj(p, ctx, x.dtype), (k, v)
 
 
 @jax.named_scope("attention")

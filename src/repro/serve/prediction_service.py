@@ -160,9 +160,11 @@ def trace_query(cfg, batch: int, seq: int) -> ProfileRecord:
 
     from repro.core import nsm as nsm_lib
     from repro.core.profiler import lm_record, lm_trace
+    from repro.models.attention import attention_impl
 
     model, step, state_sds, b = lm_trace(cfg, batch, seq)
-    closed = jax.make_jaxpr(step)(state_sds, b)
+    with attention_impl("xla"):  # the features of every platform's step
+        closed = jax.make_jaxpr(step)(state_sds, b)
     edges = nsm_lib.nsm_edges(closed)
     return lm_record(
         cfg, model, batch, seq,

@@ -89,6 +89,23 @@ def test_collect_keys_records_by_device(tmp_path, monkeypatch):
     assert [r.model_name for r in collect.all_cached()] == ["fresh"]
 
 
+def test_collect_keys_records_by_attention_path(tmp_path, monkeypatch):
+    """A label profiled through the flash kernel is never served for a step
+    that takes the materialised XLA path, nor the other way round."""
+    cache = tmp_path / "profiles.jsonl"
+    combo = {"kind": "lm", "name": "qwen2-0.5b", "batch": 2, "seq": 64}
+    mine = json.loads(collect._key(combo))
+    assert mine["attention"] == "xla"  # the CPU takes XLA
+    other = dict(mine, attention="flash>=1024")
+    cache.write_text(json.dumps({"key": json.dumps(other, sort_keys=True),
+                                 "record": record_to_json(_record("flash"))})
+                     + "\n")
+    monkeypatch.setattr(collect, "CACHE", str(cache))
+    assert collect.all_cached() == []
+    monkeypatch.setattr(collect, "_profile", lambda c: _record("fresh"))
+    assert collect.collect([combo], verbose=False)[0].model_name == "fresh"
+
+
 def test_collect_fails_on_a_failed_profile(tmp_path, monkeypatch):
     monkeypatch.setattr(collect, "CACHE", str(tmp_path / "profiles.jsonl"))
 

@@ -33,6 +33,47 @@ def test_flash_attention_allclose(b, s, h, hd, dtype, causal):
                                atol=tol, rtol=tol)
 
 
+def _attention_ref_gqa(q, k, v, causal=True):
+    """``ref.attention_ref`` in model layout, K/V repeated over each group."""
+    b, s, h, hd = q.shape
+    flat = lambda t: jnp.moveaxis(jnp.repeat(t, h // t.shape[2], axis=2), 2,
+                                  1).reshape(b * h, s, hd)
+    o = ref.attention_ref(flat(q), flat(k), flat(v), causal)
+    return jnp.moveaxis(o.reshape(b, h, s, hd), 1, 2)
+
+
+@pytest.mark.parametrize("group", [1, 7])
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("s,blocks", [(128, None), (512, (128, 256))])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_grads_match_reference(group, hd, s, blocks, dtype):
+    """Forward and the kernels' own backward against the f32 reference,
+    with grouped K/V that are never expanded."""
+    kv = 2 if group == 7 else 1
+    q = _rand(1, (1, s, kv * group, hd), dtype)
+    k = _rand(2, (1, s, kv, hd), dtype)
+    v = _rand(3, (1, s, kv, hd), dtype)
+    do = _rand(4, (1, s, kv * group, hd), dtype)
+    f32 = lambda t: t.astype(jnp.float32)
+
+    def out_and_grads(attn):
+        def f(q, k, v):
+            o, vjp = jax.vjp(attn, q, k, v)
+            return (o, *vjp(do.astype(o.dtype)))
+        return jax.jit(f)
+
+    kernel = lambda q, k, v: ops.flash_attention(q, k, v, blocks=blocks,
+                                                 interpret=True)
+    got = out_and_grads(kernel)(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        want = out_and_grads(_attention_ref_gqa)(f32(q), f32(k), f32(v))
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        assert g.dtype == dtype, name
+        err = float(jnp.max(jnp.abs(f32(g) - w)) / jnp.max(jnp.abs(w)))
+        assert err <= tol, (name, err)
+
+
 @pytest.mark.parametrize("b,l,h,p,n,chunk", [
     (1, 64, 2, 16, 8, 16), (2, 128, 4, 32, 16, 32), (1, 96, 1, 64, 32, 32),
 ])
@@ -102,7 +143,7 @@ def test_model_level_pallas_path_matches_xla():
     """StackModel forward with pallas-interpret attention == XLA path."""
     from repro.configs import get_config, reduced_config
     from repro.models import build_model
-    from repro.models.attention import set_attention_impl
+    from repro.models.attention import attention_impl
 
     cfg = reduced_config(get_config("qwen2-0.5b"))
     m = build_model(cfg)
@@ -110,10 +151,125 @@ def test_model_level_pallas_path_matches_xla():
     batch = {"tokens": jnp.arange(2 * 64, dtype=jnp.int32).reshape(2, 64) % 100,
              "labels": jnp.ones((2, 64), jnp.int32)}
     logits_xla, _ = m.forward(params, batch)
-    try:
-        set_attention_impl("pallas_interpret")
+    with attention_impl("pallas_interpret"):
         logits_pl, _ = m.forward(params, batch)
-    finally:
-        set_attention_impl("xla")
     np.testing.assert_allclose(np.asarray(logits_xla), np.asarray(logits_pl),
                                atol=2e-3, rtol=2e-3)
+
+
+@pytest.mark.parametrize("dtype,remat", [("float32", "none"),
+                                         ("bfloat16", "full")])
+def test_train_step_pallas_path_matches_xla(dtype, remat):
+    """The reduced-width qwen2 train step, kernel against XLA: the loss and
+    every weight's gradient norm."""
+    import dataclasses
+
+    from repro.configs import get_config, reduced_config
+    from repro.models import build_model
+    from repro.models.attention import TRACES, attention_impl
+
+    cfg = dataclasses.replace(reduced_config(get_config("qwen2-0.5b"), dtype=dtype),
+                              remat=remat)
+    m = build_model(cfg)
+    params = m.init(jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (2, 128), 0, cfg.vocab_size)
+    batch = {"tokens": tokens, "labels": jnp.roll(tokens, -1, axis=1)}
+
+    def readings(impl):
+        with attention_impl(impl):
+            # a new function per pin: JAX caches a trace by function
+            grad = jax.jit(jax.value_and_grad(lambda p: m.loss(p, batch)[0]))
+            loss, g = grad(params)
+        norms = jax.tree.map(lambda x: float(jnp.linalg.norm(x.astype(jnp.float32))), g)
+        return float(loss), norms
+
+    flash = TRACES.counter("flash").value
+    loss_x, norms_x = readings("xla")
+    assert TRACES.counter("flash").value == flash
+    loss_p, norms_p = readings("pallas_interpret")
+    assert TRACES.counter("flash").value > flash
+    tol = 2e-2 if dtype == "bfloat16" else 1e-4
+    assert loss_p == pytest.approx(loss_x, rel=tol)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(norms_x),
+                            jax.tree.leaves(norms_p)):
+        assert b == pytest.approx(a, rel=tol, abs=1e-6), path
+
+
+def test_auto_takes_xla_on_the_cpu():
+    from repro.configs import get_config
+    from repro.models import attention as A
+
+    assert A.get_attention_impl() == "auto" and jax.default_backend() == "cpu"
+    q = jax.ShapeDtypeStruct((1, 2048, 14, 64), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 2048, 2, 64), jnp.bfloat16)
+    assert A._flash_blocks(q, k, None) is None
+    pos = jnp.arange(2048)[None, :]
+    attn = lambda q, k, v: A.causal_attention(get_config("qwen2-0.5b"), q, k, v, pos)
+    assert "pallas_call" not in str(jax.make_jaxpr(attn)(q, k, k))
+    assert A.attention_key() == "xla"
+
+
+@pytest.mark.parametrize("sq,sk,hd,takes", [
+    (2048, 2048, 64, True), (4096, 4096, 128, True),
+    (2048, 1024, 64, False),   # not square
+    (1152, 1152, 64, True), (1100, 1100, 64, False),  # 128 tiles 1152 only
+    (2048, 2048, 80, False),   # a head width the kernel is not built for
+    (256, 256, 64, False),     # under FLASH_MIN_SEQ
+])
+def test_auto_shape_rule_on_a_tpu(monkeypatch, sq, sk, hd, takes):
+    from repro.models import attention as A
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q = jax.ShapeDtypeStruct((1, sq, 4, hd), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, sk, 2, hd), jnp.bfloat16)
+    blocks = A._flash_blocks(q, k, None)
+    assert (blocks is not None) == (takes and sq >= A.FLASH_MIN_SEQ)
+    if blocks is not None:
+        assert sq % blocks[0] == 0 and sk % blocks[1] == 0
+    # a sharded model keeps the XLA path, which GSPMD can partition
+    assert A._flash_blocks(q, k, lambda name, shape: None) is None
+    assert A.attention_key() == f"flash>={A.FLASH_MIN_SEQ}"
+
+
+def test_pinned_settings():
+    from repro.models import attention as A
+
+    q = jax.ShapeDtypeStruct((1, 64, 4, 16), jnp.float32)
+    with A.attention_impl("pallas_interpret"):
+        assert A._flash_blocks(q, q, None) == (64, 64)
+        assert A.attention_key() == "pallas_interpret"
+    with A.attention_impl("xla"):
+        assert A._flash_blocks(q, q, None) is None
+    assert A.get_attention_impl() == "auto"
+    with pytest.raises(ValueError, match="not in"):
+        A.set_attention_impl("triton")
+    with pytest.raises(ValueError, match="tile"):
+        ops.flash_blocks(1100, 64)
+
+
+def test_profile_features_do_not_depend_on_the_kernel():
+    """NSM edges and FLOPs of the profiled step equal under the kernel and
+    XLA, and equal the digest and count pinned before the kernel existed."""
+    import hashlib
+    import json
+
+    from repro.configs import get_config, reduced_config
+    from repro.core import profiler
+    from repro.models.attention import attention_impl
+
+    cfg = reduced_config(get_config("qwen2-0.5b"))
+    recs = {}
+    for impl in ("xla", "pallas_interpret"):
+        with attention_impl(impl):
+            recs[impl] = profiler.profile_lm(cfg, batch=2, seq=32, steps=1)
+    x, p = recs["xla"], recs["pallas_interpret"]
+    assert x.nsm_edges == p.nsm_edges and x.flops == p.flops
+    edges = json.dumps(sorted((f"{a}->{b}", v) for (a, b), v in x.nsm_edges.items()))
+    assert hashlib.sha256(edges.encode()).hexdigest() == NSM_DIGEST
+    assert x.flops == PROFILE_FLOPS
+
+
+# profile_lm(reduced qwen2-0.5b, batch 2, seq 32) before the kernel had a
+# backward pass: its NSM digest (test_train_tracing's) and HLO FLOPs
+NSM_DIGEST = "bbc45e031f7cf47157d3522410060fc5e70077f89999345ed674c295758c45f1"
+PROFILE_FLOPS = 50101175.0

@@ -47,12 +47,21 @@ def _on(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_flash_attention_qwen2_widths(one_chip):
+@pytest.mark.parametrize("batch,seq", [(8, 1024), (2, 4096)])
+def test_flash_attention_qwen2_widths(one_chip, batch, seq):
+    """Forward and backward, with the 2 KV heads not expanded to 14."""
     cfg = get_config("qwen2-0.5b")
-    shape = (8, 1024, cfg.num_heads, cfg.resolved_head_dim)
-    q = _on(one_chip, shape, jnp.bfloat16)
-    compiled = ops.flash_attention.lower(q, q, q, causal=True).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    hd = cfg.resolved_head_dim
+    q = _on(one_chip, (batch, seq, cfg.num_heads, hd), jnp.bfloat16)
+    kv = _on(one_chip, (batch, seq, cfg.num_kv_heads, hd), jnp.bfloat16)
+
+    def fwd_bwd(q, k, v, do):
+        o, vjp = jax.vjp(ops.flash_attention, q, k, v)
+        return o, vjp(do)
+
+    text = jax.jit(fwd_bwd).lower(q, kv, kv, q).compile().as_text()
+    # forward, dQ and dK/dV kernels
+    assert text.count("tpu_custom_call") == 3
 
 
 @pytest.mark.parametrize("arch", ["qwen2-0.5b", "mamba2-370m"])
@@ -96,6 +105,32 @@ def test_launcher_step_qwen2_fits_one_chip(one_chip):
     compiled = trainer.jstep.lower(state, batch).compile()
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes > 0.9 * ma.argument_size_in_bytes  # donated
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert total < V5E_HBM_BYTES, total / 2**30
+
+
+def test_launcher_step_with_the_kernel_at_s4096(one_chip):
+    """The donated step at b2 x s4096 with attention on the kernel: it
+    compiles, holds the kernels and fits one chip."""
+    from repro.models import build_model
+    from repro.models.attention import attention_impl
+    from repro.train import optimizer as opt_lib
+    from repro.train import step as step_lib
+    from repro.train.loop import LoopConfig, Trainer
+
+    with attention_impl("pallas"):
+        model = build_model(get_config("qwen2-0.5b"))
+        opt_cfg = opt_lib.OptConfig()
+        trainer = Trainer(model, opt_cfg, LoopConfig(batch=2, seq=4096))
+        on_chip = lambda t: jax.tree.map(
+            lambda s: _on(one_chip, s.shape, s.dtype), t)
+        state = on_chip(step_lib.state_shapes(model, opt_cfg))
+        batch = on_chip({"tokens": jax.ShapeDtypeStruct((2, 4096), jnp.int32),
+                         "labels": jax.ShapeDtypeStruct((2, 4096), jnp.int32)})
+        compiled = trainer.jstep.lower(state, batch).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    ma = compiled.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert total < V5E_HBM_BYTES, total / 2**30
