@@ -1,15 +1,24 @@
 """Training traffic: the launcher's train step, back to back.
 
 Set-up builds the one object the window drives: the program's
-``Trainer`` (its donated, jitted step inside its ``StepRunner``) with the
-optimizer the traffic file states, fed by the program's ``ShardedLoader``
-from Zipf token rows made from the seed. The weights are made on the
+``Trainer`` (its donated, jitted step ``jstep``) with the optimizer the
+traffic file states, fed by the program's ``ShardedLoader`` from Zipf
+token rows made from the seed. The weights are made on the
 device in one jitted call. The first ``check_steps`` steps compile the
 step and are read for the check (loss, first clipped gradient, change of
-the weights); then the window runs steps until ``--seconds`` have passed.
+the weights). Those steps and the window call the same compiled step,
+``Trainer.jstep``, on the same feed. The window keeps ``AHEAD_S``
+seconds of steps dispatched ahead of the one it waits for, so that the
+chip stays fed while the host stands still, and reads no loss until the
+step is that far behind; when ``--seconds`` have passed it sends nothing
+more, waits for every step it sent, and reads the clock after that wait:
+the rate is all of those steps over all of that time. The program's
+``StepRunner``, which blocks on every step, is not in the window.
 After the window the device's peak memory is read (and, in a traced run,
-the compiled step's footprint), the program's state is freed, and the plain reference trains the same weights on the same rows
-for the same steps. ``correct`` holds the program's readings to the
+the compiled step's footprint and its HLO text, whose ``op_name``
+metadata names each op's scopes), the program's state is freed, and the
+plain reference trains the same weights on the same rows for the same
+steps. ``correct`` holds the program's readings to the
 reference's by the limits in the workload file (``checks.py``).
 
 Traffic keys: ``batch``, ``seq``, ``tokens`` and ``zipf_a`` (the token
@@ -20,6 +29,7 @@ law, ``weights.Tokens``), ``check_steps``,
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import gc
 import math
@@ -30,6 +40,8 @@ from typing import Any, Dict
 from chipbench import checks, flops
 from chipbench import weights as W
 from chipbench.reference import train as reference
+
+AHEAD_S = 6.0  # seconds of steps the window keeps dispatched ahead
 
 
 def program_config(cfg: Dict[str, Any]):
@@ -74,16 +86,17 @@ class CompileCounter:
         self.active = False
 
 
-def step_footprint(jstep, args) -> int:
-    """Device bytes the compiled step holds at once: its arguments, its
+def step_footprint(compiled) -> int:
+    """Device bytes a compiled step holds at once: its arguments, its
     outputs that do not reuse a donated argument, and its temporaries."""
-    m = jstep.lower(*args).compile().memory_analysis()
+    m = compiled.memory_analysis()
     return int(m.argument_size_in_bytes + m.output_size_in_bytes
                - m.alias_size_in_bytes + m.temp_size_in_bytes)
 
 
 def setup(cfg: Dict[str, Any], tr: Dict[str, Any], seed: int) -> SimpleNamespace:
-    """The Trainer, its state after the checked steps, its feed, and readings."""
+    """The Trainer, its state after the checked steps, its feed, readings,
+    and the shortest checked step after the first, in seconds."""
     import jax
     import jax.numpy as jnp
 
@@ -118,8 +131,12 @@ def setup(cfg: Dict[str, Any], tr: Dict[str, Any], seed: int) -> SimpleNamespace
     loader = ShardedLoader(data, None)
 
     readings: Dict[str, Any] = {"loss": []}
+    step_s = math.inf
     for t in range(tr["check_steps"]):
-        state, out = trainer.runner(state, next(loader))
+        t0 = time.perf_counter()
+        state, out = jax.block_until_ready(trainer.jstep(state, next(loader)))
+        if t:
+            step_s = min(step_s, time.perf_counter() - t0)
         readings["loss"].append(float(out["loss"]))
         if t == 0:
             scale = 1.0 / (1.0 - opt_cfg.beta1)  # m after one step = (1 - b1) g
@@ -128,7 +145,7 @@ def setup(cfg: Dict[str, Any], tr: Dict[str, Any], seed: int) -> SimpleNamespace
     kept = state["opt"]["master"] or state["params"]
     readings["change"] = {p: float(x) for p, x in change(key, kept).items()}
     return SimpleNamespace(trainer=trainer, state=state, loader=loader,
-                           readings=readings)
+                           readings=readings, step_s=step_s)
 
 
 def run(ctx) -> Dict[str, Any]:
@@ -137,20 +154,14 @@ def run(ctx) -> Dict[str, Any]:
     cfg, tr = ctx.config, ctx.traffic
     batch, seq = tr["batch"], tr["seq"]
     s = setup(cfg, tr, ctx.seed)
-    trainer, state, loader, readings = s.trainer, s.state, s.loader, s.readings
+    jstep, state, loader, readings = s.trainer.jstep, s.state, s.loader, s.readings
+    ahead = max(1, math.ceil(AHEAD_S / s.step_s)) if math.isfinite(s.step_s) else 1
     del s
 
     if ctx.trace:
         jax.profiler.start_trace(ctx.trace_dir)
-        jstep = trainer.runner.step_fn
-
-        def dispatch(*args):
-            with jax.profiler.TraceAnnotation("dispatch"):
-                return jstep(*args)
-
-        trainer.runner.step_fn = dispatch
-
     steps = 0
+    sent = collections.deque()  # the losses of steps not yet waited for
     with CompileCounter() as compiles:
         t0 = time.perf_counter()
         setup_s = t0 - ctx.t0
@@ -159,11 +170,17 @@ def run(ctx) -> Dict[str, Any]:
                 with jax.profiler.StepTraceAnnotation("train", step_num=steps):
                     with jax.profiler.TraceAnnotation("data"):
                         b = next(loader)
-                    with jax.profiler.TraceAnnotation("step"):
-                        state, out = trainer.runner(state, b)
+                    with jax.profiler.TraceAnnotation("dispatch"):
+                        state, out = jstep(state, b)
+                    sent.append(out["loss"])
+                    if len(sent) > ahead:
+                        with jax.profiler.TraceAnnotation("step"):
+                            sent.popleft().block_until_ready()
                 steps += 1
                 if time.perf_counter() - t0 >= ctx.seconds:
                     break
+            with jax.profiler.TraceAnnotation("step"):
+                jax.block_until_ready((state, out, list(sent)))
         t1 = time.perf_counter()
     if ctx.trace:
         jax.profiler.stop_trace()
@@ -171,8 +188,12 @@ def run(ctx) -> Dict[str, Any]:
 
     stats = jax.devices()[0].memory_stats() or {}
     loader.close()
-    step_bytes = step_footprint(trainer.jstep, (state, b)) if ctx.trace else None
-    del state, out, b, trainer, loader
+    step_bytes = hlo_text = None
+    if ctx.trace:  # one compile (from the cache) gives footprint and op names
+        compiled = jstep.lower(state, b).compile()
+        step_bytes, hlo_text = step_footprint(compiled), compiled.as_text()
+    state_step = int(state["step"])
+    del state, out, b, jstep, loader, sent
     gc.collect()
 
     t_ref = time.perf_counter()
@@ -190,9 +211,11 @@ def run(ctx) -> Dict[str, Any]:
         "memory_peak_bytes": stats.get("peak_bytes_in_use", 0),
         "bytes_limit": stats.get("bytes_limit", 0),
         "step_bytes": step_bytes,
+        "hlo_text": hlo_text,
         "tokens_per_s": tokens_per_s,
         "flops_per_token": flops.train_flops_per_token(cfg, seq),
-        "log": {"window_steps": steps, "window_s": t1 - t0,
+        "log": {"window_steps": steps, "window_s": t1 - t0, "ahead": ahead,
+                "state_step": state_step,
                 "compiles_in_window": compiles.count, "reference_s": ref_s,
                 "last_loss": last_loss, "program": readings["loss"],
                 "reference": ref["loss"], "memory_stats": stats,

@@ -23,13 +23,13 @@ Readings, as the benchmark compares them:
 
 from __future__ import annotations
 
-import importlib
 import math
 from typing import Any, Dict, List
 
 import jax
 import jax.numpy as jnp
 
+from chipbench import registry
 from chipbench import weights as W
 from chipbench.reference.precision import DOTS
 
@@ -58,7 +58,7 @@ def change_norms(specs: W.Spec, key, tree) -> Dict[str, jax.Array]:
 def run(cfg: Dict[str, Any], traffic: Dict[str, Any], seed: int,
         precision: str = "highest", steps: int = 3,
         rows_used: int = 0) -> Dict[str, Any]:
-    model = importlib.import_module(f"chipbench.reference.{cfg['reference']}")
+    model = registry.reference(cfg)
     dot = DOTS[precision]
     opt = traffic["optimizer"]
     b1, b2, eps, wd = opt["beta1"], opt["beta2"], opt["eps"], opt["weight_decay"]

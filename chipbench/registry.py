@@ -4,6 +4,10 @@ Everything that belongs to one configuration, traffic mix, driver or
 per-layer metric lives in a file of its own under this directory:
 
 - ``configs/<config>.json``: the model configuration as it is run;
+- ``reference/<reference>.py``: the plain reference of the architecture
+  a configuration names under ``reference``, with its facts: ``loss``,
+  ``leaf_specs`` (the program's weight layout), ``param_count`` and
+  ``mixer_flops_per_token`` (model FLOPs);
 - ``traffic/<traffic>.json``: the traffic's parameters, naming its driver;
 - ``workloads/<cell>.json``: configuration, traffic, chips, why, limits;
 - ``drivers/<driver>.py``: the one generator for a kind of traffic;
@@ -39,7 +43,10 @@ def workload(name: str, root: pathlib.Path = ROOT) -> Dict[str, Any]:
 
 
 def config(name: str, root: pathlib.Path = ROOT) -> Dict[str, Any]:
-    return _load(root, "configs", name)
+    """The configuration file, with ``_root``: where its reference is looked for."""
+    cfg = _load(root, "configs", name)
+    cfg["_root"] = str(root)
+    return cfg
 
 
 def traffic(name: str, root: pathlib.Path = ROOT) -> Dict[str, Any]:
@@ -55,16 +62,32 @@ def driver(name: str):
     return importlib.import_module(f"chipbench.drivers.{name}")
 
 
+def _module_at(path: pathlib.Path, kind: str):
+    name = f"chipbench_{kind}_{path.stem.replace('.', '_').replace('-', '_')}"
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reference(cfg: Dict[str, Any]):
+    """The reference module that ``cfg["reference"]`` names:
+    ``reference/<name>.py`` under the configuration's root where it has
+    one there, else ``chipbench.reference.<name>``."""
+    name = cfg["reference"]
+    root = pathlib.Path(cfg.get("_root", ROOT))
+    path = root / "reference" / f"{name}.py"
+    if root == ROOT or not path.is_file():
+        return importlib.import_module(f"chipbench.reference.{name}")
+    return _module_at(path, "reference")
+
+
 def metric_reader(name: str, root: pathlib.Path = ROOT):
     """The ``read(outputs)`` function of ``metrics/<name>.py``."""
     path = root / "metrics" / f"{name}.py"
     if not path.is_file():
         raise KeyError(f"no reader for metric {name!r} ({path})")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module_at(path, "metric").read
 
 
 def benchmark(repo: pathlib.Path = REPO) -> Dict[str, Any]:

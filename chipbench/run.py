@@ -11,6 +11,12 @@ line of standard output: ``correct``, ``attempted``, ``failed``,
 per-layer metrics), ``device``, with ``--trace 1`` ``breakdown``, and
 last ``checks``: each number compared with its limit, which also close
 standard error.
+
+A per-layer metric's reader (``metrics/<name>.py``) gets the traffic driver's
+outputs with the cell's ``config`` and ``traffic``, the run's
+``device_kind``, and ``trace``: the profiler trace joined to the
+compiled step's op names and reduced by ``xtrace.reduce`` (device time
+by op and by scope, the program's spans, the window's steps, idle time).
 """
 
 from __future__ import annotations
@@ -92,13 +98,14 @@ def main(argv: Optional[List[str]] = None, root: pathlib.Path = registry.ROOT,
                           traffic=traffic, seed=args.seed, seconds=args.seconds,
                           trace=bool(args.trace), trace_dir=str(trace_dir), t0=T0)
     out = registry.driver(traffic["driver"]).run(ctx)
+    out.update(config=ctx.config, traffic=traffic)
     bench = registry.benchmark(repo)
 
     device["memory_peak_bytes"] = out["memory_peak_bytes"]
     result: Dict[str, Any] = {"correct": bool(out["correct"]),
                               "attempted": out["attempted"], "failed": out["failed"]}
     if args.trace:
-        planes = xtrace.load(xtrace.find_xplane(str(trace_dir)))
+        planes = xtrace.load(xtrace.find_xplane(str(trace_dir)), out.pop("hlo_text"))
         shutil.rmtree(trace_dir, ignore_errors=True)
         out["trace"] = xtrace.reduce(planes)
         out["device_kind"] = device["kind"]

@@ -11,8 +11,10 @@ import contextlib
 import gzip
 import io
 import json
+import math
 import os
 import pathlib
+import re
 import shutil
 import subprocess
 import sys
@@ -31,6 +33,48 @@ TESTDATA = pathlib.Path(__file__).resolve().parents[1] / "testdata"
 
 TINY = dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
             num_key_value_heads=2, num_hidden_layers=2, vocab_size=500)
+BOTH = ["qwen2-0.5b.train_b8s1024", "qwen2-0.5b.train_b2s4096"]
+SCOPE_READERS = ["attention_ms.train", "mlp_ms.train", "lm_head_ms.train",
+               "optimizer_ms.train", "flash_attention_roofline"]
+
+# A reference module for an architecture the benchmark does not have: one
+# causal attention head of width d over a tied embedding.
+TOY = '''
+import jax
+import jax.numpy as jnp
+
+from chipbench import weights as W
+
+
+def leaf_specs(cfg):
+    d, v = cfg["hidden_size"], cfg["vocab_size"]
+    dt = W.DTYPES[cfg["torch_dtype"]]
+    specs = {"embed/table": ((v, d), dt, ("normal", 0.02))}
+    specs.update({f"layer/w{x}": ((d, d), dt, ("normal", 0.02)) for x in "qkvo"})
+    return specs
+
+
+def param_count(cfg):
+    return cfg["vocab_size"] * cfg["hidden_size"] + 4 * cfg["hidden_size"] ** 2
+
+
+def mixer_flops_per_token(cfg, seq):
+    return 4.0 * cfg["hidden_size"] * (seq + 1) / 2
+
+
+def loss(params, tokens, labels, cfg, dot):
+    table, p = params["embed"]["table"], params["layer"]
+    x = table[tokens]
+    s = tokens.shape[1]
+    q, k, v = (dot("bsd,de->bse", x, p[w]) for w in ("wq", "wk", "wv"))
+    scores = dot("bsd,btd->bst", q, k) / jnp.sqrt(jnp.float32(x.shape[-1]))
+    causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
+    probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
+    x = x + dot("bse,ed->bsd", dot("bst,btd->bsd", probs, v), p["wo"])
+    logits = dot("bsd,vd->bsv", x, table)
+    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - gold)
+'''
 
 
 @pytest.fixture(autouse=True)
@@ -123,6 +167,28 @@ def test_tiny_run_prints_the_contract_line(tmp_path, monkeypatch):
     assert set(res["checks"]) == set(registry.workload(CELL)["limits"])
 
 
+def test_the_window_waits_for_every_step_it_sent(tmp_path):
+    """Steps run ahead of the host; every one sent is done and counted when
+    the window's clock stops."""
+    import time
+    from types import SimpleNamespace
+    from chipbench.drivers import train_loop
+
+    root = _tiny_root(tmp_path)
+    ctx = SimpleNamespace(workload=registry.workload("tiny.train", root),
+                          config=registry.config("tiny", root),
+                          traffic=registry.traffic("tiny", root), seed=3,
+                          seconds=1.0, trace=False, trace_dir=str(tmp_path / "t"),
+                          t0=time.perf_counter())
+    out = train_loop.run(ctx)
+    log = out["log"]
+    assert log["ahead"] > 1
+    assert log["state_step"] == ctx.traffic["check_steps"] + out["attempted"]
+    assert out["attempted"] == log["window_steps"]
+    assert out["tokens_per_s"] == pytest.approx(
+        log["window_steps"] * 4 * 64 / log["window_s"])
+
+
 def _broken_step(kind):
     from repro.train import step as step_lib
     real = step_lib.make_train_step
@@ -170,7 +236,8 @@ def test_hbm_share_counts_the_steps_temporaries(tmp_path):
     cfg, tr = registry.config("tiny", root), registry.traffic("tiny", root)
     s = train_loop.setup(cfg, tr, 11)
     state_bytes = sum(x.nbytes for x in jax.tree.leaves(s.state))
-    footprint = train_loop.step_footprint(s.trainer.jstep, (s.state, next(s.loader)))
+    footprint = train_loop.step_footprint(
+        s.trainer.jstep.lower(s.state, next(s.loader)).compile())
     s.loader.close()
     assert footprint > state_bytes
     read = registry.metric_reader("hbm_peak_frac.train")
@@ -262,6 +329,151 @@ def test_mixer_flops_by_hand():
     # 4 * 24 layers * 14 heads * 64 * (1024 + 1) / 2
     assert flops.mixer_flops_per_token(q, 1024) == 44_083_200
     assert flops.train_flops_per_token(q, 1024) == 6 * flops.param_count(q) + 3 * 44_083_200
+
+
+def test_qwen2_leaves_keep_their_order():
+    """A leaf's draw is ``fold_in(key, its index among the sorted paths)``:
+    the same paths give the same weights."""
+    assert sorted(W.leaf_specs(registry.config("qwen2-0.5b"))) == [
+        "embed/table", "final_norm/scale",
+        "layers/L0/mixer/bk", "layers/L0/mixer/bq", "layers/L0/mixer/bv",
+        "layers/L0/mixer/wk", "layers/L0/mixer/wo", "layers/L0/mixer/wq",
+        "layers/L0/mixer/wv", "layers/L0/mlp/wi", "layers/L0/mlp/wo",
+        "layers/L0/norm1/scale", "layers/L0/norm2/scale"]
+
+
+# --- a configuration added by files alone ---------------------------------
+
+def _toy_root(tmp_path: pathlib.Path) -> pathlib.Path:
+    """A registry with a reference module, configuration, traffic and cell
+    of its own, and the benchmark's readers."""
+    root = tmp_path / "bench"
+    shutil.copytree(registry.ROOT / "metrics", root / "metrics")
+    for d in ("configs", "traffic", "workloads", "reference"):
+        (root / d).mkdir(parents=True)
+    (root / "reference" / "toy.py").write_text(TOY)
+    (root / "configs" / "toy-1l.json").write_text(json.dumps(
+        {"reference": "toy", "hidden_size": 32, "vocab_size": 300,
+         "torch_dtype": "bfloat16"}))
+    tr = dict(registry.traffic("train_b8s1024"), batch=2, seq=16, ref_rows_per_block=1)
+    (root / "traffic" / "toy_b2s16.json").write_text(json.dumps(tr))
+    (root / "workloads" / "toy-1l.train.json").write_text(json.dumps(
+        {"config": "toy-1l", "traffic": "toy_b2s16", "chips": 1, "why": "w",
+         "limits": {}}))
+    bench = registry.benchmark()
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "workloads" in m:
+            m["workloads"] = ["toy-1l.train"]
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return root
+
+
+def test_a_configuration_is_added_by_files(tmp_path):
+    root = _toy_root(tmp_path)
+    cfg = registry.config("toy-1l", root)
+    tr = registry.traffic("toy_b2s16", root)
+    assert sorted(W.leaf_specs(cfg)) == ["embed/table", "layer/wk", "layer/wo",
+                                         "layer/wq", "layer/wv"]
+    n = 300 * 32 + 4 * 32 * 32
+    assert flops.train_flops_per_token(cfg, 16) == 6 * n + 3 * 4 * 32 * 17 / 2
+    ref = reference.run(cfg, tr, 2**31 + 3, steps=2)
+    assert all(abs(x - math.log(300)) < 0.5 for x in ref["loss"])
+    assert set(ref["change"]) == set(W.leaf_specs(cfg))
+
+    trace = {"busy_s": 0.9, "window_s": 1.0, "devices": 1, "steps": 4,
+             "scopes": {"attention": 0.004, "flash": 0.002, "mlp": 0.008,
+                        "lm_head": 0.012, "optimizer": 0.016}}
+    out = {"trace": trace, "config": cfg, "traffic": tr, "device_kind": "TPU v5 lite",
+           "flops_per_token": flops.train_flops_per_token(cfg, 16),
+           "tokens_per_s": 1e6, "step_bytes": 1, "bytes_limit": 4}
+    got = run.per_layer("toy-1l.train", out, registry.benchmark(root), root)
+    assert set(got) == {m["name"] for m in registry.benchmark()["per_layer"]}
+    assert {k: got[k]["value"] for k in SCOPE_READERS} == pytest.approx({
+        "attention_ms.train": 1.0, "mlp_ms.train": 2.0, "lm_head_ms.train": 3.0,
+        "optimizer_ms.train": 4.0,
+        # 3 * 4 * 32 * 17 / 2 FLOPs a token, 2 * 16 tokens a step, 4 steps
+        "flash_attention_roofline": 100 * 3 * 1088 * 32 * 4 / (0.002 * 197e12)})
+
+
+def test_the_harness_names_no_architecture():
+    from repro.configs import list_archs
+
+    names = {re.match(r"[a-z]+", a).group(0) for a in list_archs()}
+    names |= {p.stem for p in (registry.ROOT / "reference").glob("*.py")} - {
+        "__init__", "train", "precision"}
+    names |= {p.stem for p in (registry.ROOT / "configs").glob("*.json")}
+    files = [registry.ROOT / f for f in ("weights.py", "flops.py", "run.py",
+                                         "xtrace.py", "xscope.py")]
+    files += sorted((registry.ROOT / "drivers").glob("*.py"))
+    for f in files:
+        text = f.read_text().lower()
+        for name in names:
+            assert not re.search(rf"(?<![a-z]){re.escape(name)}", text), (f.name, name)
+
+
+# --- per-layer readers ----------------------------------------------------
+
+def _flash_planes(flash_ns, steps):
+    """A hand-made trace: ``steps`` steps, each with one attention
+    projection and one kernel call under ``flash`` of ``flash_ns``."""
+    body = "jit(train_step)/jvp()/while/body/closed_call/attention/"
+    host = [["window", 0, 1e9]]
+    events, names = [], []
+    for i in range(steps):
+        t = i * 1e9 / steps
+        host.append(["train", t, 1e9 / steps])
+        events += [[f"%fusion.{i} = bf16[8]{{0}} fusion(%a)", t, 1e6],
+                   [f"%custom-call.{i} = bf16[8]{{0}} custom-call(%q)", t + 2e6, flash_ns]]
+        names += [body + "dot_general", body + "flash/pallas_call"]
+    return [{"name": "/host:CPU", "lines": [{"name": "python3", "events": host}]},
+            {"name": "/device:TPU:0", "lines": [
+                {"name": "XLA Ops", "events": events, "op_names": names}]}]
+
+
+def test_flash_roofline_by_hand():
+    cfg = registry.config("qwen2-0.5b")
+    tr = registry.traffic("train_b2s4096")
+    r = xtrace.reduce(_flash_planes(flash_ns=50e6, steps=2))
+    out = {"trace": r, "config": cfg, "traffic": tr, "device_kind": "TPU v5 lite"}
+    read = registry.metric_reader("flash_attention_roofline")
+    # 4 * 24 layers * 14 heads * 64 * (4096 + 1) / 2 = 176203776 forward
+    # FLOPs a token, three times over 2 x 4096 tokens a step, 2 steps, in
+    # 2 x 50 ms against 197 TFLOP/s
+    assert read(out) == pytest.approx(100 * 3 * 176_203_776 * 8192 * 2 / (0.1 * 197e12))
+    assert 40 < read(out) < 45
+    ms = registry.metric_reader("attention_ms.train")(out)
+    assert ms == pytest.approx(51.0)  # the projection's 1 ms and the kernel's 50
+    planes = _flash_planes(flash_ns=50e6, steps=2)
+    planes[1]["lines"][0]["op_names"] = [n.replace("flash/", "")
+                                         for n in planes[1]["lines"][0]["op_names"]]
+    out["trace"] = xtrace.reduce(planes)
+    assert read(out) is None
+    assert registry.metric_reader("attention_ms.train")(out) == pytest.approx(51.0)
+
+
+def test_readers_on_a_scoped_trace_recorded_on_the_chip():
+    """Two steps of b8s1024 on a TPU v5e, recorded with no ``flash``
+    scope: every reader but the kernel's reads."""
+    planes = json.loads(gzip.decompress(
+        (TESTDATA / "qwen2_train_scoped_trace.json.gz").read_bytes()))
+    out = {"trace": xtrace.reduce(planes), "config": registry.config("qwen2-0.5b"),
+           "traffic": registry.traffic("train_b8s1024"), "device_kind": "TPU v5 lite"}
+    got = {n: registry.metric_reader(n)(out) for n in SCOPE_READERS}
+    assert got == pytest.approx({
+        "attention_ms.train": 125.68545, "mlp_ms.train": 123.41772300000002,
+        "lm_head_ms.train": 50.5839785, "optimizer_ms.train": 22.685008000000003,
+        "flash_attention_roofline": None}, rel=1e-9)
+
+
+def test_benchmark_lists_the_readers_in_both_cells():
+    bench = registry.benchmark()
+    layers = {m["name"]: m for m in bench["per_layer"]}
+    for name in SCOPE_READERS:
+        m = layers[name]
+        assert m["workloads"] == BOTH and m["moves"] == "train_tokens_per_s"
+        assert m["source"] == "device_trace"
+    assert layers["flash_attention_roofline"]["layer"] == "kernels"
+    assert {layers[n]["layer"] for n in SCOPE_READERS[:4]} == {"model step"}
 
 
 # --- trace reduction ------------------------------------------------------

@@ -1,8 +1,8 @@
 """Weights and tokens made from ``--seed``, on the device, in one call.
 
-The weights are laid out as the program stores them (stacked over
-layers, projections flattened, the vocabulary padded to a multiple of
-256), and the plain reference reads the same tree. Each leaf is drawn
+The weights are laid out as the program stores them, as the
+configuration's reference module states it (``leaf_specs``), and the
+plain reference reads the same tree. Each leaf is drawn
 from its own key, ``fold_in(seed_key, leaf_index)``, so one leaf can be
 made again alone. Values are rounded to the dtype the configuration
 serves them in; the reference computes on those rounded values in fp32.
@@ -18,6 +18,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chipbench import registry
 
 # (shape, dtype, init) per leaf path; init is ("normal", std) or
 # ("const", value).
@@ -38,30 +40,8 @@ def seed_key(seed: int) -> jax.Array:
 
 
 def leaf_specs(cfg: Dict[str, Any]) -> Spec:
-    dt = DTYPES[cfg["torch_dtype"]]
-    f32 = jnp.float32
-    if cfg["reference"] == "qwen2":
-        d, f = cfg["hidden_size"], cfg["intermediate_size"]
-        h, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-        hd, n = d // h, cfg["num_hidden_layers"]
-        w = ("normal", 0.02)
-        lay = "layers/L0/"
-        return {
-            "embed/table": ((padded_vocab(cfg["vocab_size"]), d), dt, w),
-            "final_norm/scale": ((d,), f32, ("const", 1.0)),
-            lay + "norm1/scale": ((n, d), f32, ("const", 1.0)),
-            lay + "norm2/scale": ((n, d), f32, ("const", 1.0)),
-            lay + "mixer/wq": ((n, d, h * hd), dt, w),
-            lay + "mixer/wk": ((n, d, kv * hd), dt, w),
-            lay + "mixer/wv": ((n, d, kv * hd), dt, w),
-            lay + "mixer/wo": ((n, h * hd, d), dt, w),
-            lay + "mixer/bq": ((n, h * hd), dt, w),
-            lay + "mixer/bk": ((n, kv * hd), dt, w),
-            lay + "mixer/bv": ((n, kv * hd), dt, w),
-            lay + "mlp/wi": ((n, d, 2, f), dt, w),
-            lay + "mlp/wo": ((n, f, d), dt, w),
-        }
-    raise KeyError(f"no weights for reference {cfg['reference']!r}")
+    """The program's weight layout, from the configuration's reference module."""
+    return registry.reference(cfg).leaf_specs(cfg)
 
 
 def _draw(key, shape, init) -> jax.Array:
