@@ -1,21 +1,26 @@
 """Causal attention on one TPU chip: the flash kernel against XLA.
 
-At qwen2-0.5b's widths (14 query heads over 2 KV heads of 64, bf16) and
-8192 tokens a step, for each sequence length:
+At an architecture's attention widths (``--arch``; by default qwen2-0.5b:
+14 query heads over 2 KV heads of 64; Moonlight-16B-A3B's latent
+attention: 16 heads, q/k 192 and v 128 wide), bf16, and ``--tokens``
+tokens a step, for each sequence length:
 
-- the kernel's outputs and gradients against an f32 reference;
+- the kernel's outputs and gradients against an f32 reference (at most
+  4096 tokens, where the reference's S x S tensors fit);
 - one layer's RoPE and attention core (``causal_attention``), forward and
   forward + backward, on the XLA path, on the kernel at each (block_q,
   block_k) of the sweep, and through the bundled splash-attention kernel
-  as a yardstick;
-- the launcher's whole donated train step with attention pinned to XLA
+  as a yardstick where q, k and v are of one width;
+- for qwen2-0.5b, the launcher's whole donated train step with attention
+  pinned to XLA
   and to the kernel (at the sweep's best blocks, or with ``--no-layer`` at
   ``ops.flash_blocks``'s); with ``--profile``, the
   device time per step of its named scopes (``flash`` apart from the rest
   of ``attention``) and of its longest ops, from a profiler trace.
 
-    python benchmarks/attention_tpu.py [--seqs 512 1024 2048 4096]
-        [--blocks 256 512 1024 2048] [--no-layer] [--profile]
+    python benchmarks/attention_tpu.py [--arch qwen2-0.5b] [--tokens 8192]
+        [--seqs 512 1024 2048 4096] [--blocks 256 512 1024 2048]
+        [--no-layer] [--profile]
         [--out chiprun_out/attention_tpu.json]
 
 It needs a TPU and exits non-zero without one. A layer's timing is the
@@ -63,13 +68,21 @@ def _fwd_bwd(attn):
     return fwd, both
 
 
+def _widths(cfg):
+    """(q/k head width, v head width) of an architecture's self-attention."""
+    if cfg.kv_lora_rank:
+        return cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim
+    return cfg.resolved_head_dim, cfg.resolved_head_dim
+
+
 def _inputs(batch, seq, cfg, dtype=jnp.bfloat16):
-    h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
+    h, kv = cfg.num_heads, cfg.num_kv_heads
+    dqk, dv = _widths(cfg)
     ks = jax.random.split(jax.random.key(seq), 4)
-    q = jax.random.normal(ks[0], (batch, seq, h, hd), dtype)
-    k = jax.random.normal(ks[1], (batch, seq, kv, hd), dtype)
-    v = jax.random.normal(ks[2], (batch, seq, kv, hd), dtype)
-    do = jax.random.normal(ks[3], (batch, seq, h, hd), dtype)
+    q = jax.random.normal(ks[0], (batch, seq, h, dqk), dtype)
+    k = jax.random.normal(ks[1], (batch, seq, kv, dqk), dtype)
+    v = jax.random.normal(ks[2], (batch, seq, kv, dv), dtype)
+    do = jax.random.normal(ks[3], (batch, seq, h, dv), dtype)
     return q, k, v, do
 
 
@@ -124,7 +137,7 @@ def layer_times(batch, seq, cfg, blocks_sweep, reps):
     for bq, bk in blocks_sweep:
         if seq % bq or seq % bk:
             continue
-        ops.FLASH_BLOCKS[seq, cfg.resolved_head_dim] = (bq, bk)
+        ops.FLASH_BLOCKS[seq, _widths(cfg)[0]] = (bq, bk)
         row("flash", "pallas", lambda q, k, v: model_path(q, k, v),
             block_q=bq, block_k=bk)
     ops.FLASH_BLOCKS.clear()
@@ -132,10 +145,11 @@ def layer_times(batch, seq, cfg, blocks_sweep, reps):
     # the yardstick: splash attention per batch row, after the same RoPE,
     # q pre-scaled as the kernel's
     h = cfg.num_heads
-    scale = cfg.resolved_head_dim ** -0.5
+    dqk, dv = _widths(cfg)
+    scale = dqk ** -0.5
     heads = lambda t: jnp.swapaxes(t, 1, 2)
     for b in (512, 1024):
-        if seq % b:
+        if seq % b or dqk != dv:
             continue
         sizes = splash.BlockSizes(block_q=b, block_kv=b, block_kv_compute=b,
                                   block_q_dkv=b, block_kv_dkv=b,
@@ -169,9 +183,9 @@ def profile(compiled, state, batch_, steps=3, top=6):
     ops hold the others and are left out."""
     import tempfile
 
-    from chipbench import xscope, xtrace
+    from chipbench import xtrace
 
-    names = xscope.op_names(compiled.as_text())
+    names = xtrace.op_names(compiled.as_text())
     with tempfile.TemporaryDirectory() as d:
         jax.profiler.start_trace(d)
         for _ in range(steps):
@@ -245,6 +259,8 @@ def step_time(batch, seq, impl, reps, opt, traced=False):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--arch", default="qwen2-0.5b")
+    ap.add_argument("--tokens", type=int, default=TOKENS)
     ap.add_argument("--seqs", type=int, nargs="+", default=[512, 1024, 2048, 4096])
     ap.add_argument("--blocks", type=int, nargs="+", default=[256, 512, 1024, 2048])
     ap.add_argument("--no-layer", action="store_true")
@@ -259,20 +275,23 @@ def main(argv=None) -> int:
     from repro.configs import get_config
     from repro.kernels import ops
 
-    cfg = get_config("qwen2-0.5b")
+    cfg = get_config(args.arch)
+    dqk = _widths(cfg)[0]
     here = os.path.dirname(os.path.abspath(__file__))
     with open(os.path.join(here, "..", "chipbench", "traffic", "train_b8s1024.json")) as f:
         opt = json.load(f)["optimizer"]
     out = {"device": {"platform": dev.platform, "device_kind": dev.device_kind,
-                      "count": jax.device_count()},
+                      "count": jax.device_count()}, "arch": args.arch,
            "check": {}, "layer": [], "step": []}
     sweep = [(bq, bk) for bq, bk in itertools.product(args.blocks, args.blocks)
              if bq <= bk]
     best = {}
     for seq in [] if args.no_layer else args.seqs:
-        batch = TOKENS // seq
-        # batch 1: the f32 reference holds its S x S tensors
-        out["check"][seq] = check(1, seq, cfg, ops.flash_blocks(seq, 64))
+        batch = args.tokens // seq
+        # batch 1 and at most 4096 tokens: the f32 reference holds its
+        # S x S tensors
+        cs = min(seq, 4096)
+        out["check"][seq] = check(1, cs, cfg, ops.flash_blocks(cs, dqk))
         print(json.dumps({"seq": seq, "check": out["check"][seq]}), flush=True)
         rows = layer_times(batch, seq, cfg, sweep, REPS)
         out["layer"] += rows
@@ -281,7 +300,7 @@ def main(argv=None) -> int:
         best[seq] = (b["block_q"], b["block_k"])
     out["best_blocks"] = {s: list(b) for s, b in best.items()}
     print(json.dumps({"best_blocks": out["best_blocks"]}), flush=True)
-    for seq in args.seqs:
+    for seq in args.seqs if args.arch == "qwen2-0.5b" else []:
         ops.FLASH_BLOCKS[seq, 64] = best.get(seq, ops.flash_blocks(seq, 64))
         for impl in ("xla", "pallas"):
             out["step"].append(step_time(TOKENS // seq, seq, impl, STEP_REPS,

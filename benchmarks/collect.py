@@ -50,7 +50,7 @@ MID_NETS = ["vgg13", "vgg16", "resnet34", "se_resnet18", "mobilenet_v2",
 SLOW_NETS = ["vgg19", "resnet101", "resnet152", "preact_resnet152"]
 
 LM_ARCHS = ["qwen2-0.5b", "chatglm3-6b", "phi4-mini-3.8b", "mamba2-370m",
-            "whisper-tiny", "moonshot-v1-16b-a3b", "jamba-v0.1-52b",
+            "whisper-tiny", "moonlight-16b-a3b", "jamba-v0.1-52b",
             "llama-3.2-vision-90b"]
 
 

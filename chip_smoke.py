@@ -100,9 +100,11 @@ def _close(name: str, got, want, bound: float, why: str) -> None:
         raise AssertionError(f"{name}: error {err} over bound {bound}")
 
 
-def check_kernels(attn_cfg, ssd_cfg, batch: int, seq: int,
+def check_kernels(attn_cfg, ssd_cfg, mla_cfg, batch: int, seq: int,
                   interpret: bool = False) -> None:
-    """Each Pallas kernel against its reference, on one set of inputs."""
+    """Each Pallas kernel against its reference, on one set of inputs; the
+    flash kernel also at ``mla_cfg``'s latent-attention widths (q/k heads
+    wider than v heads)."""
     from repro.kernels import ops, ref
     from repro.models.ssm import ssd_chunked_ref
 
@@ -116,18 +118,23 @@ def check_kernels(attn_cfg, ssd_cfg, batch: int, seq: int,
     bf16_out = ("bf16 output: rounding is 2^-9 relative, and f32 operands "
                 "may enter the MXU as bf16; 1e-2 is a few times both")
 
-    h, kv, hd = (attn_cfg.num_heads, attn_cfg.num_kv_heads,
-                 attn_cfg.resolved_head_dim)
-    q = rand((batch, seq, h, hd), jnp.bfloat16)
-    k, v = (rand((batch, seq, kv, hd), jnp.bfloat16) for _ in range(2))
-    got = ops.flash_attention(q, k, v, causal=True, interpret=interpret)
-    # the reference takes every query head's own copy of its group's K/V
-    flat = lambda t: f32(jnp.moveaxis(jnp.repeat(t, h // t.shape[2], axis=2),
-                                      2, 1).reshape(batch * h, seq, hd))
-    with jax.default_matmul_precision("highest"):
-        want = ref.attention_ref(flat(q), flat(k), flat(v), causal=True)
-    want = jnp.moveaxis(want.reshape(batch, h, seq, hd), 1, 2)
-    _close("flash_attention", got, want, 1e-2, bf16_out)
+    def flash(name, h, kv, dqk, dv):
+        q = rand((batch, seq, h, dqk), jnp.bfloat16)
+        k = rand((batch, seq, kv, dqk), jnp.bfloat16)
+        v = rand((batch, seq, kv, dv), jnp.bfloat16)
+        got = ops.flash_attention(q, k, v, causal=True, interpret=interpret)
+        # the reference takes every query head's own copy of its group's K/V
+        flat = lambda t: f32(jnp.moveaxis(jnp.repeat(t, h // t.shape[2], axis=2),
+                                          2, 1).reshape(batch * h, seq, t.shape[-1]))
+        with jax.default_matmul_precision("highest"):
+            want = ref.attention_ref(flat(q), flat(k), flat(v), causal=True)
+        want = jnp.moveaxis(want.reshape(batch, h, seq, dv), 1, 2)
+        _close(name, got, want, 1e-2, bf16_out)
+
+    hd = attn_cfg.resolved_head_dim
+    flash("flash_attention", attn_cfg.num_heads, attn_cfg.num_kv_heads, hd, hd)
+    flash("flash_attention.mla", mla_cfg.num_heads, mla_cfg.num_kv_heads,
+          mla_cfg.qk_nope_head_dim + mla_cfg.qk_rope_head_dim, mla_cfg.v_head_dim)
 
     d = attn_cfg.d_model
     x = rand((batch * seq, d), jnp.bfloat16)
@@ -288,7 +295,7 @@ def main(argv=None) -> int:
         phase("four chips", four_chips, OUT)
     else:
         phase("kernels", check_kernels, cfg, get_config("mamba2-370m"),
-              *UNSEEN)
+              get_config("moonlight-16b-a3b"), *UNSEEN)
         records = phase("profile", profile, cfg, PROFILED, FAST_NETS)
         predictor = phase("serve", fit_and_serve, records, cfg, PROFILED,
                           UNSEEN, OUT)

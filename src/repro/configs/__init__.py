@@ -15,7 +15,7 @@ from repro.configs.base import SHAPES, ModelConfig, ShapeConfig, shape_applicabl
 
 _MODULES = {
     "jamba-v0.1-52b": "jamba_v0_1_52b",
-    "moonshot-v1-16b-a3b": "moonshot_v1_16b_a3b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "arctic-480b": "arctic_480b",
     "chatglm3-6b": "chatglm3_6b",
     "phi4-mini-3.8b": "phi4_mini_3_8b",
@@ -48,7 +48,7 @@ def reduced_config(cfg: ModelConfig, dtype: str = "float32") -> ModelConfig:
         while heads % kv:
             kv -= 1
     repl = dict(
-        num_layers=period * min(2, cfg.num_periods),
+        num_layers=cfg.first_dense_layers + period * min(2, cfg.num_periods),
         d_model=64,
         num_heads=heads,
         num_kv_heads=kv,
@@ -67,6 +67,13 @@ def reduced_config(cfg: ModelConfig, dtype: str = "float32") -> ModelConfig:
     if cfg.num_experts:
         repl["num_experts"] = min(cfg.num_experts, 4)
         repl["top_k"] = min(cfg.top_k, 2)
+        if cfg.moe_d_ff:
+            repl["moe_d_ff"] = 32
+        if cfg.experts_held:
+            repl["experts_held"] = min(cfg.experts_held, 2)
+    if cfg.kv_lora_rank:
+        repl.update(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                    v_head_dim=16)
     if cfg.encoder_layers:
         repl["encoder_layers"] = 2
     return dataclasses.replace(cfg, **repl)

@@ -42,6 +42,16 @@ class ModelConfig:
     dense_residual: bool = False  # Arctic: dense FFN in parallel with MoE
     capacity_factor: float = 1.25
     moe_group_size: int = 512     # tokens per dispatch group
+    moe_d_ff: int = 0             # an expert's width (0 -> d_ff)
+    # "softmax": top-k of softmax scores, capacity-bounded dispatch;
+    # "sigmoid": DeepSeek-V3's noaux_tc router (top-k of sigmoid scores plus
+    # a selection bias, the unbiased scores normalised and scaled by
+    # routed_scaling), dropless
+    moe_scoring: str = "softmax"
+    routed_scaling: float = 1.0
+    shared_experts: int = 0       # a shared SwiGLU of shared_experts * moe_d_ff
+    experts_held: int = 0         # routed experts this device holds (0 -> all)
+    first_dense_layers: int = 0   # dense-MLP layers ahead of the periodic stack
 
     # --- attention ---
     qkv_bias: bool = False
@@ -49,6 +59,13 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     attn_every: int = 1           # attention on layers where i % attn_every == attn_offset
     attn_offset: int = 0          # (non-attention layers are SSM)
+    # latent attention (MLA) when kv_lora_rank > 0: k/v come from a
+    # kv_lora_rank-wide latent, q/k heads are qk_nope + qk_rope wide (RoPE on
+    # the qk_rope part, whose key all heads share), v heads v_head_dim wide
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0
@@ -68,6 +85,7 @@ class ModelConfig:
 
     # --- misc ---
     norm: str = "rmsnorm"         # rmsnorm | layernorm
+    norm_eps: float = 1e-6
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
     remat: str = "full"           # none | dots | full
@@ -78,6 +96,14 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // max(1, self.num_heads))
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
 
     @property
     def d_inner(self) -> int:
@@ -92,7 +118,13 @@ class ModelConfig:
         return self.encoder_layers > 0
 
     def layer_kind(self, i: int) -> LayerKind:
-        """(mixer, mlp) kind of decoder layer ``i``."""
+        """(mixer, mlp) kind of decoder layer ``i``. The first
+        ``first_dense_layers`` are self-attention with a dense MLP (DeepSeek's
+        ``first_k_dense_replace``); the pattern counts from the layer after
+        them."""
+        if i < self.first_dense_layers:
+            return ("attn", "dense")
+        i -= self.first_dense_layers
         if self.cross_every and i % self.cross_every == self.cross_offset:
             mixer = "cross"
         elif self.attn_every > 1:
@@ -110,6 +142,11 @@ class ModelConfig:
         return (mixer, mlp)
 
     @property
+    def periodic_layers(self) -> int:
+        """Layers after the leading dense ones: the scanned stack."""
+        return self.num_layers - self.first_dense_layers
+
+    @property
     def period(self) -> int:
         """Length of the repeating layer pattern."""
         p = 1
@@ -120,18 +157,20 @@ class ModelConfig:
         if self.num_experts:
             p = math.lcm(p, self.moe_every)
         # Find the smallest period consistent with layer_kind.
-        while self.num_layers % p != 0:
+        while self.periodic_layers % p != 0:
             p += 1
         return p
 
     @property
     def num_periods(self) -> int:
-        return self.num_layers // self.period
+        return self.periodic_layers // self.period
 
     def pattern(self) -> List[LayerKind]:
-        kinds = [self.layer_kind(i) for i in range(self.num_layers)]
+        """(mixer, mlp) kinds of one period of the scanned stack."""
+        lead = self.first_dense_layers
+        kinds = [self.layer_kind(lead + i) for i in range(self.periodic_layers)]
         p = self.period
-        for i in range(self.num_layers):
+        for i in range(len(kinds)):
             assert kinds[i] == kinds[i % p], (
                 f"layer pattern of {self.name} is not periodic with period {p}")
         return kinds[:p]

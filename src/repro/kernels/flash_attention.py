@@ -1,10 +1,12 @@
 """Flash attention as Pallas TPU kernels: a forward pass and its backward.
 
 Blockwise online softmax over (block_q x block_k) tiles, so the S x S
-scores never reach HBM. The layout is head-major: q (B, H, S, hd) and
-k/v (B, KV, S, hd), H a multiple of KV (grouped-query attention). A query
-head reads its group's K/V block through the BlockSpec index map, so K/V
-are never expanded to H heads. The caller scales q by 1/sqrt(hd).
+scores never reach HBM. The layout is head-major: q and k (B, H|KV, S,
+d_qk), v (B, KV, S, d_v), H a multiple of KV (grouped-query attention);
+the value width may differ from the query/key width (latent attention's
+192-wide q/k and 128-wide v). A query head reads its group's K/V block
+through the BlockSpec index map, so K/V are never expanded to H heads.
+The caller scales q by 1/sqrt(d_qk).
 
 Forward: grid (B, H, q blocks, k blocks), k innermost. The running max,
 the running sum and the output accumulator stay in f32 VMEM scratch over
@@ -18,8 +20,8 @@ delta = rowsum(dO * O) comes from XLA.
 
 MXU operands keep the input dtype (bf16 on the train path) and
 accumulate in f32; max, sum, exp and the accumulators are f32, and P and
-dS are cast to the input dtype for their products. A head narrower than
-the MXU (hd < 128) leaves it spare columns: V carries a ones column
+dS are cast to the input dtype for their products. A value head narrower
+than the MXU (d_v < 128) leaves it spare columns: V carries a ones column
 there, so P·V also gives the softmax's row sums.
 
 Causal: a block strictly above the diagonal is neither computed nor
@@ -105,9 +107,9 @@ def _visible(q_start, k_start, shape, q_axis):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, acc_sc, *l_sc,
-                causal: bool, bq: int, bk: int, hd: int):
+                causal: bool, bq: int, bk: int, dv: int):
     # without ``l_sc`` the row sums come out of the MXU: v carries a ones
-    # column at lane ``hd`` (see ``_ones_column``)
+    # column at lane ``dv`` (see ``_ones_column``)
     qi, ki = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ki == 0)
@@ -138,8 +140,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, acc_sc, *l_sc,
     @pl.when(ki == pl.num_programs(3) - 1)
     def _finish():
         acc = acc_sc[...]
-        l = l_sc[0][...] if l_sc else acc[:, hd:hd + 1]
-        o_ref[...] = (acc[:, :hd] / l).astype(o_ref.dtype)
+        l = l_sc[0][...] if l_sc else acc[:, dv:dv + 1]
+        o_ref[...] = (acc[:, :dv] / l).astype(o_ref.dtype)
         lse_ref[0] = (m_sc[...] + jnp.log(l))[:, 0]
 
 
@@ -209,52 +211,55 @@ def _params(n_parallel: int, n_arbitrary: int):
 
 
 def _check(q, k, bq, bk):
-    b, h, sq, hd = q.shape
+    b, h, sq, dqk = q.shape
     kv, sk = k.shape[1], k.shape[2]
     if h % kv:
         raise ValueError(f"{h} query heads do not group over {kv} KV heads")
     if sq % bq or sk % bk:
         raise ValueError(f"cannot tile q {sq} x k {sk} with blocks {bq} x {bk}")
-    return b, h, kv, sq, sk, hd
+    return b, h, kv, sq, sk, dqk
 
 
 def _ones_column(v):
-    """v (B, KV, S, hd) padded to LANES lanes with ones at lane hd, so P·V
+    """v (B, KV, S, d_v) padded to LANES lanes with ones at lane d_v, so P·V
     also gives P's row sums in columns the MXU computes anyway."""
-    b, kv, s, hd = v.shape
+    b, kv, s, dv = v.shape
     pad = [jnp.ones((b, kv, s, 1), v.dtype),
-           jnp.zeros((b, kv, s, LANES - hd - 1), v.dtype)]
+           jnp.zeros((b, kv, s, LANES - dv - 1), v.dtype)]
     return jnp.concatenate([v] + pad, axis=-1)
 
 
-def _q_major_specs(group, causal, bq, bk, hd):
-    """BlockSpecs on the grid (B, H, q blocks, k blocks): a block of q rows,
-    the index map of its group's K/V block (clamped under the causal mask
-    to the last block the q block sees), and a block of a row vector."""
+def _q_major_specs(group, causal, bq, bk):
+    """On the grid (B, H, q blocks, k blocks): the BlockSpec of a block of
+    rows of a (B, H, S, width) array, the index map of the group's K/V
+    block (clamped under the causal mask to the last block the q block
+    sees), and the BlockSpec of a block of a row vector."""
     def kv_map(bi, hi, qi, ki):
         if causal:
             ki = jnp.minimum(ki, _last_k_block(qi, bq, bk))
         return bi, hi // group, ki, 0
 
-    q_spec = pl.BlockSpec((None, None, bq, hd), lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+    q_spec = lambda w: pl.BlockSpec((None, None, bq, w),
+                                    lambda bi, hi, qi, ki: (bi, hi, qi, 0))
     row_spec = pl.BlockSpec((None, None, 1, bq), lambda bi, hi, qi, ki: (bi, hi, 0, qi))
     return q_spec, kv_map, row_spec
 
 
 def _forward(q, k, v, causal, bq, bk, interpret):
-    b, h, kv, sq, sk, hd = _check(q, k, bq, bk)
-    fold = hd < LANES
+    b, h, kv, sq, sk, dqk = _check(q, k, bq, bk)
+    dv = v.shape[-1]
+    fold = dv < LANES
     if fold:
         v = _ones_column(v)
-    q_spec, kv_map, row_spec = _q_major_specs(h // kv, causal, bq, bk, hd)
-    kernel = functools.partial(_fwd_kernel, causal=causal, bq=bq, bk=bk, hd=hd)
+    q_spec, kv_map, row_spec = _q_major_specs(h // kv, causal, bq, bk)
+    kernel = functools.partial(_fwd_kernel, causal=causal, bq=bq, bk=bk, dv=dv)
     return pl.pallas_call(
         kernel,
         grid=(b, h, sq // bq, sk // bk),
-        in_specs=[q_spec, pl.BlockSpec((None, None, bk, hd), kv_map),
+        in_specs=[q_spec(dqk), pl.BlockSpec((None, None, bk, dqk), kv_map),
                   pl.BlockSpec((None, None, bk, v.shape[-1]), kv_map)],
-        out_specs=[q_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=[q_spec(dv), row_spec],
+        out_shape=[jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
                    jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, v.shape[-1]), jnp.float32)]
@@ -266,22 +271,24 @@ def _forward(q, k, v, causal, bq, bk, interpret):
 
 
 def _backward(q, k, v, o, lse, do, causal, bq, bk, interpret):
-    b, h, kv, sq, sk, hd = _check(q, k, bq, bk)
+    b, h, kv, sq, sk, dqk = _check(q, k, bq, bk)
+    dv = v.shape[-1]
     group = h // kv
     nq, nk = sq // bq, sk // bk
     di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
                  axis=-1)[:, :, None, :]                          # (B,H,1,S)
 
     # dQ: the forward's grid
-    q_spec, kv_map, row_spec = _q_major_specs(group, causal, bq, bk, hd)
-    kv_spec = pl.BlockSpec((None, None, bk, hd), kv_map)
+    q_spec, kv_map, row_spec = _q_major_specs(group, causal, bq, bk)
+    k_spec = pl.BlockSpec((None, None, bk, dqk), kv_map)
+    v_spec = pl.BlockSpec((None, None, bk, dv), kv_map)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, bq=bq, bk=bk),
         grid=(b, h, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
+        in_specs=[q_spec(dqk), k_spec, v_spec, q_spec(dv), row_spec, row_spec],
+        out_specs=q_spec(dqk),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, dqk), jnp.float32)],
         compiler_params=_params(3, 1),
         name="flash_bwd_dq",
         interpret=interpret,
@@ -297,30 +304,32 @@ def _backward(q, k, v, o, lse, do, causal, bq, bk, interpret):
         _, hi, qi, _ = q_map(bi, kvi, ki, g, qi)
         return bi, hi, 0, qi
 
-    q_spec = pl.BlockSpec((None, None, bq, hd), q_map)
-    kv_spec = pl.BlockSpec((None, None, bk, hd),
-                           lambda bi, kvi, ki, g, qi: (bi, kvi, ki, 0))
+    q_spec = lambda w: pl.BlockSpec((None, None, bq, w), q_map)
+    kv_spec = lambda w: pl.BlockSpec((None, None, bk, w),
+                                     lambda bi, kvi, ki, g, qi: (bi, kvi, ki, 0))
     row_spec = pl.BlockSpec((None, None, 1, bq), row_map)
-    dk, dv = pl.pallas_call(
+    dk, dv_ = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, bq=bq, bk=bk),
         grid=(b, kv, nk, group, nq),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[kv_spec, kv_spec],
+        in_specs=[q_spec(dqk), kv_spec(dqk), kv_spec(dv), q_spec(dv), row_spec,
+                  row_spec],
+        out_specs=[kv_spec(dqk), kv_spec(dv)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
-                        pltpu.VMEM((bk, hd), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bk, dqk), jnp.float32),
+                        pltpu.VMEM((bk, dv), jnp.float32)],
         compiler_params=_params(3, 2),
         name="flash_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, lse, di)
-    return dq, dk, dv
+    return dq, dk, dv_
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention_bhsd(q, k, v, causal: bool, block_q: int, block_k: int,
                          interpret: bool = False):
-    """q (B, H, Sq, hd) pre-scaled, k/v (B, KV, Sk, hd) -> (B, H, Sq, hd)."""
+    """q (B, H, Sq, d_qk) pre-scaled, k (B, KV, Sk, d_qk), v (B, KV, Sk,
+    d_v) -> (B, H, Sq, d_v)."""
     return _forward(q, k, v, causal, block_q, block_k, interpret)[0]
 
 

@@ -41,10 +41,12 @@ def _pick_block(s: int, target: int) -> int:
 # a sweep on a TPU v5e chip (PERF.md); other shapes take the
 # largest square block of at most DEFAULT_BLOCK rows that tiles them
 FLASH_BLOCKS = {(512, 64): (512, 512), (1024, 64): (1024, 1024),
-                (2048, 64): (2048, 2048), (4096, 64): (2048, 2048)}
+                (2048, 64): (2048, 2048), (4096, 64): (2048, 2048),
+                (8192, 192): (1024, 1024)}
 DEFAULT_BLOCK = 1024
-# the head widths the kernel is built and tested for
-FLASH_HEAD_DIMS = (64, 128)
+# the query/key head widths the kernel is built and tested for (192: latent
+# attention's qk_nope 128 + qk_rope 64, with 128-wide value heads)
+FLASH_HEAD_DIMS = (64, 128, 192)
 # a block of a row vector (the log-sum-exp) is a multiple of the lane
 # width unless it spans the whole extent
 LANES = fa.LANES
@@ -64,7 +66,8 @@ def flash_blocks(seq: int, head_dim: int):
 @partial(jax.jit, static_argnames=("causal", "blocks", "interpret"))
 def flash_attention_bhsd(q, k, v, causal: bool = True, blocks=None,
                          interpret: bool = False):
-    """Heads leading: q (B,H,S,hd), k/v (B,KV,S,hd) with KV dividing H.
+    """Heads leading: q (B,H,S,d_qk), k (B,KV,S,d_qk), v (B,KV,S,d_v) with
+    KV dividing H; q is scaled by 1/sqrt(d_qk).
 
     ``blocks`` is (block_q, block_k); by default ``flash_blocks``'s.
     Differentiable: the backward pass is the kernels' own.
@@ -79,10 +82,31 @@ def flash_attention_bhsd(q, k, v, causal: bool = True, blocks=None,
 @partial(jax.jit, static_argnames=("causal", "blocks", "interpret"))
 def flash_attention(q, k, v, causal: bool = True, blocks=None,
                     interpret: bool = False):
-    """Model layout: q (B,S,H,hd), k/v (B,S,KV,hd); see ``flash_attention_bhsd``."""
+    """Model layout: q/k (B,S,H|KV,d_qk), v (B,S,KV,d_v); see
+    ``flash_attention_bhsd``."""
     heads = lambda t: jnp.swapaxes(t, 1, 2)
     return heads(flash_attention_bhsd(heads(q), heads(k), heads(v), causal,
                                       blocks, interpret))
+
+
+# (rows, contraction, columns) tile of the megablox grouped matmul: the
+# fastest forward + backward of the expert layer's SwiGLU at
+# Moonlight-16B-A3B's widths in a sweep on a TPU v5e (PERF.md)
+GMM_TILING = (512, 1024, 1024)
+
+
+def grouped_matmul(lhs, rhs, sizes, interpret: bool = False):
+    """Rows of ``lhs`` (M, K) in consecutive groups of ``sizes`` (G,) times
+    their group's matrix of ``rhs`` (G, K, N), through the megablox ``gmm``
+    kernel, whose work follows the rows the groups hold. Rows past the
+    groups are left unwritten: the caller masks them. Differentiable (the
+    kernel's own backward)."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    (m, k), n = lhs.shape, rhs.shape[-1]
+    tm, tk, tn = GMM_TILING
+    tiling = (_pick_block(m, tm), min(k, tk), min(n, tn))
+    return gmm(lhs, rhs, sizes, lhs.dtype, tiling, None, None, False, interpret)
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))

@@ -72,7 +72,7 @@ NO_MASTER = {"arctic-480b"}
 # fp32 trees, so accumulation *adds* memory — measured in §Perf)
 TRAIN_ACCUM = {"llama-3.2-vision-90b": 4, "arctic-480b": 4,
                "qwen2.5-32b": 4, "jamba-v0.1-52b": 4,
-               "moonshot-v1-16b-a3b": 2}
+               "moonlight-16b-a3b": 2}
 
 
 def _lower_cell(arch: str, shape_name: str, mesh, rules: shd.ShardingRules,

@@ -20,7 +20,7 @@ import time
 
 ARCH_ORDER = [
     "whisper-tiny", "qwen2-0.5b", "mamba2-370m", "chatglm3-6b",
-    "phi4-mini-3.8b", "moonshot-v1-16b-a3b", "jamba-v0.1-52b",
+    "phi4-mini-3.8b", "moonlight-16b-a3b", "jamba-v0.1-52b",
     "qwen2.5-32b", "arctic-480b", "llama-3.2-vision-90b",
 ]
 SHAPE_ORDER = ["train_4k", "decode_32k", "prefill_32k", "long_500k"]
@@ -32,7 +32,7 @@ SHAPE_ORDER = ["train_4k", "decode_32k", "prefill_32k", "long_500k"]
 TRAIN_SCHEME = {
     "whisper-tiny": "dp", "qwen2-0.5b": "dp", "mamba2-370m": "dp",
     "chatglm3-6b": "dp", "phi4-mini-3.8b": "dp",
-    "moonshot-v1-16b-a3b": "sp", "jamba-v0.1-52b": "sp",
+    "moonlight-16b-a3b": "sp", "jamba-v0.1-52b": "sp",
     "qwen2.5-32b": "sp", "arctic-480b": "sp", "llama-3.2-vision-90b": "sp",
 }
 

@@ -18,6 +18,12 @@ of a head width the kernel supports, from ``FLASH_MIN_SEQ`` tokens; XLA
 otherwise, so CPU runs keep the einsum path. ``attention_impl("xla")``
 (or ``"pallas"``, ``"pallas_interpret"`` for tests) pins the choice while
 a function is traced.
+
+Latent attention (MLA, DeepSeek-V2/V3) is chosen by ``cfg.kv_lora_rank``.
+Training and prefill use its expanded form: q and k heads of
+qk_nope + qk_rope, where the rope part of k is one head that all heads
+share, and v heads of ``v_head_dim``; they take the same causal path (and
+the kernel) as GQA, and the cache holds the expanded per-head k and v.
 """
 
 from __future__ import annotations
@@ -28,6 +34,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.models import layers as L
 from repro.models.module import spec
 from repro.obs import events
 from repro.obs.metrics import MetricsRegistry
@@ -54,8 +61,10 @@ def get_attention_impl() -> str:
 
 @contextlib.contextmanager
 def attention_impl(impl: str):
-    """Pin the implementation inside the block. JAX caches a trace by
-    function, so trace a function made inside the block."""
+    """Pin the implementation inside the block: of causal attention here
+    and of the held experts' grouped matmul (``moe._grouped``), the one
+    pin of the model's kernel paths. JAX caches a trace by function, so
+    trace a function made inside the block."""
     was = _IMPL
     set_attention_impl(impl)
     try:
@@ -80,6 +89,8 @@ def attention_key() -> str:
 
 
 def attn_spec(cfg):
+    if cfg.kv_lora_rank:
+        return _mla_spec(cfg)
     d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     p = {
         "wq": spec((d, h * hd), ("embed", "heads_flat")),
@@ -94,8 +105,43 @@ def attn_spec(cfg):
     return p
 
 
+def _mla_spec(cfg):
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {
+        "wq": spec((d, h * (dn + dr)), ("embed", "heads_flat")),
+        "wkv_a": spec((d, r + dr), ("embed", None)),      # latent + rope key
+        "kv_norm": L.norm_spec(cfg, r),
+        "wkv_b": spec((r, h * (dn + dv)), (None, "heads_flat")),
+        "wo": spec((h * dv, d), ("heads_flat", "embed")),
+    }
+
+
 def _heads(cfg):
     return cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
+
+
+def _qkv(p, cfg, x):
+    """Self-attention's q (B,S,H,d_qk), k (B,S,KV,d_qk), v (B,S,KV,d_v),
+    before RoPE."""
+    if cfg.kv_lora_rank:
+        return _mla_qkv(p, cfg, x)
+    return (_project_q(p, cfg, x), *_project_kv(p, cfg, x, x.dtype))
+
+
+def _mla_qkv(p, cfg, x):
+    """MLA expanded: q = x W_q; [c_kv, k_pe] = x W_kva; [k_nope, v] =
+    rmsnorm(c_kv) W_kvb per head; k = [k_nope, k_pe], k_pe broadcast over
+    the heads."""
+    b, s, _ = x.shape
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = (x @ p["wq"].astype(x.dtype)).reshape(b, s, h, dn + dr)
+    ckv = x @ p["wkv_a"].astype(x.dtype)
+    c = L.apply_norm(p["kv_norm"], ckv[..., :r], cfg.norm_eps)
+    kv = (c @ p["wkv_b"].astype(x.dtype)).reshape(b, s, h, -1)
+    k_pe = jnp.broadcast_to(ckv[..., None, r:], (b, s, h, dr))
+    return q, jnp.concatenate([kv[..., :dn], k_pe], axis=-1), kv[..., dn:]
 
 
 def _project_q(p, cfg, x):
@@ -124,10 +170,10 @@ def _out_proj(p, ctx, dtype):
 
 def _expand_kv(cfg, k):
     """(B,S,KV,hd) -> (B,S,H,hd) by broadcasting each KV head over its group."""
-    h, kv, hd = _heads(cfg)
+    h, kv = cfg.num_heads, cfg.num_kv_heads
     if kv == h:
         return k
-    b, s = k.shape[:2]
+    b, s, hd = k.shape[0], k.shape[1], k.shape[-1]
     k = jnp.broadcast_to(k[:, :, :, None, :], (b, s, kv, h // kv, hd))
     return k.reshape(b, s, h, hd)
 
@@ -246,7 +292,7 @@ def _chunked_causal_attention(q, k, v, block_q: int):
         return carry, blk_fn(i, qi)
 
     _, ctx = jax.lax.scan(blk, 0, (jnp.arange(nblk), qb))
-    return jnp.moveaxis(ctx, 0, 1).reshape(b, sq, h, hd)
+    return jnp.moveaxis(ctx, 0, 1).reshape(b, sq, h, v.shape[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -264,8 +310,7 @@ def _constrain(shard, name, x):
 @jax.named_scope("attention")
 def apply_self_attn(p, cfg, x, positions, shard=None, causal=True):
     """Full-sequence self-attention. Returns (y, (k_cache, v_cache))."""
-    q = _project_q(p, cfg, x)
-    k, v = _project_kv(p, cfg, x, x.dtype)
+    q, k, v = _qkv(p, cfg, x)
     if causal:
         ctx, k = causal_attention(cfg, q, k, v, positions, shard)
     else:
@@ -297,8 +342,7 @@ def decode_self_attn(p, cfg, x_t, cache, pos, shard=None):
     positions are the engine's job (slot-aligned continuous batching);
     attention masking stays per-request via ``pos``.
     """
-    q = _project_q(p, cfg, x_t)
-    k_t, v_t = _project_kv(p, cfg, x_t, x_t.dtype)
+    q, k_t, v_t = _qkv(p, cfg, x_t)
     q = _apply_rope(cfg, q, pos[:, None])
     k_t = _apply_rope(cfg, k_t, pos[:, None])
     sel = (jnp.arange(cache["k"].shape[1]) == pos[0])[None, :, None, None]
@@ -310,8 +354,8 @@ def decode_self_attn(p, cfg, x_t, cache, pos, shard=None):
     # traffic multiplier for 40q/8kv heads); scores stay sharded over the
     # cache-seq axis (flash-decoding split) — otherwise GSPMD gathers the
     # whole cache per layer.
-    b = q.shape[0]
-    h, kv, hd = _heads(cfg)
+    b, hd = q.shape[0], q.shape[-1]
+    h, kv = cfg.num_heads, cfg.num_kv_heads
     g = h // kv
     q5 = q.reshape(b, 1, kv, g, hd)
     kq = k.astype(q.dtype)
@@ -328,7 +372,6 @@ def decode_self_attn(p, cfg, x_t, cache, pos, shard=None):
     scores = jnp.where(mask, scores, neg)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     ctx = jnp.einsum("bkgqs,bskd->bqkgd", probs, vq)
-    ctx = ctx.reshape(b, 1, h, hd)
     return _out_proj(p, ctx, x_t.dtype), {"k": k, "v": v}
 
 
@@ -341,10 +384,17 @@ def decode_cross_attn(p, cfg, x_t, cache):
 
 
 def _apply_rope(cfg, x, positions):
-    from repro.models.layers import apply_rope
-    return apply_rope(x, positions, cfg.rope_theta, cfg.rope_style)
+    if cfg.qk_rope_head_dim:  # MLA: RoPE on the trailing rope dims alone
+        n = x.shape[-1] - cfg.qk_rope_head_dim
+        return jnp.concatenate(
+            [x[..., :n], L.apply_rope(x[..., n:], positions, cfg.rope_theta)],
+            axis=-1)
+    return L.apply_rope(x, positions, cfg.rope_theta, cfg.rope_style)
 
 
 def kv_cache_shape(cfg, batch: int, seq: int):
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+    if cfg.kv_lora_rank:
+        dk, dv = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim
+        return {"k": (batch, seq, kv, dk), "v": (batch, seq, kv, dv)}
     return {"k": (batch, seq, kv, hd), "v": (batch, seq, kv, hd)}

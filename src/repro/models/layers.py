@@ -90,12 +90,16 @@ def mlp_spec(cfg, d_ff=None):
     }
 
 
-@jax.named_scope("mlp")
-def apply_mlp(p, x):
+def swiglu(p, x):
+    """The gated MLP's math, in no scope of its own (the MoE layer's shared
+    experts run it under ``shared``)."""
     gu = jnp.einsum("...d,dgf->...gf", x, p["wi"].astype(x.dtype))
     g, u = gu[..., 0, :], gu[..., 1, :]
     h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
     return jnp.einsum("...f,fd->...d", h, p["wo"].astype(x.dtype))
+
+
+apply_mlp = jax.named_scope("mlp")(swiglu)
 
 
 # ---------------------------------------------------------------------------

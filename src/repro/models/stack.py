@@ -4,6 +4,9 @@ One class, parameterized by ``ModelConfig``: dense / MoE / hybrid(SSM+attn) /
 VLM(cross-attn) / enc-dec(audio) / pure-SSM stacks are all instances of a
 *periodic layer pattern* scanned with ``jax.lax.scan`` over stacked
 parameters (HLO size O(period), compile time independent of depth).
+Leading dense layers (``cfg.first_dense_layers``, DeepSeek's
+``first_k_dense_replace``) run ahead of that scan in a scan of their own,
+under the same remat; their weights sit under ``dense_layers``.
 
 Entry points:
   ``loss(params, batch)``          training objective (+ metrics)
@@ -41,6 +44,7 @@ class StackModel:
         self.cfg = cfg
         self.dtype = _dtype_of(cfg)
         self.pattern = cfg.pattern()
+        self.lead = [("attn", "dense")] if cfg.first_dense_layers else []
         self.sharder = sharder  # name -> Sharding | None
 
     # ------------------------------------------------------------------
@@ -83,6 +87,10 @@ class StackModel:
             "layers": stack_specs(layer_specs, cfg.num_periods, None),
             "final_norm": L.norm_spec(cfg),
         }
+        if self.lead:
+            tree["dense_layers"] = stack_specs(
+                {"L0": self._layer_spec(*self.lead[0])},
+                cfg.first_dense_layers, None)
         if cfg.is_encoder_decoder:
             enc = {f"L{i}": self._layer_spec("enc_attn", "dense")
                    for i in range(1)}  # encoder period is 1
@@ -118,16 +126,19 @@ class StackModel:
     # ------------------------------------------------------------------
     # Forward pass
     # ------------------------------------------------------------------
+    def _norm(self, p, x):
+        return L.apply_norm(p, x, self.cfg.norm_eps)
+
     def _constrain(self, x, name):
         if self.sharder is None:
             return x
         s = self.sharder(name, x.shape)
         return jax.lax.with_sharding_constraint(x, s) if s is not None else x
 
-    def _apply_layer(self, i: int, p, x, positions, memory):
+    def _apply_layer(self, kind, p, x, positions, memory):
         cfg = self.cfg
-        mixer, mlp = self.pattern[i]
-        h = L.apply_norm(p["norm1"], x)
+        mixer, mlp = kind
+        h = self._norm(p["norm1"], x)
         if mixer == "attn":
             y, _ = attn.apply_self_attn(p["mixer"], cfg, h, positions,
                                         shard=self.sharder)
@@ -140,7 +151,7 @@ class StackModel:
         elif mixer == "attn_cross":
             y, _ = attn.apply_self_attn(p["mixer"], cfg, h, positions)
             x = x + y
-            hx = L.apply_norm(p["norm_x"], x)
+            hx = self._norm(p["norm_x"], x)
             y, _ = attn.apply_cross_attn(p["cross"], cfg, hx, memory)
         elif mixer == "ssm":
             y, _ = ssm_lib.apply_ssm(p["mixer"], cfg, h)
@@ -148,19 +159,8 @@ class StackModel:
             raise ValueError(mixer)
         x = x + y
         x = self._constrain(x, "acts")
-        aux = jnp.zeros((), jnp.float32)
+        x, aux = self._mlp_block(mlp, p, x)
         if mlp != "none":
-            h = L.apply_norm(p["norm2"], x)
-            if mlp == "dense":
-                y = L.apply_mlp(p["mlp"], h)
-            elif mlp == "moe":
-                y, aux = moe_lib.apply_moe(p["mlp"], self.cfg, h,
-                                           shard=self.sharder)
-            elif mlp == "moe_dense":
-                y, aux = moe_lib.apply_moe(p["mlp"], self.cfg, h,
-                                           shard=self.sharder)
-                y = y + L.apply_mlp(p["mlp_dense"], h)
-            x = x + y
             x = self._constrain(x, "acts")
         return x, aux
 
@@ -174,18 +174,21 @@ class StackModel:
         return jax.checkpoint(f)
 
     def _run_stack(self, params, x, positions, memory):
-        def body(carry, layer_params):
-            h, aux = carry
-            for i in range(len(self.pattern)):
-                h, a = self._apply_layer(i, layer_params[f"L{i}"], h,
-                                         positions, memory)
-                aux = aux + a
-            return (h, aux), None
+        def scan(kinds, carry, stacked):
+            def body(carry, layer_params):
+                h, aux = carry
+                for i, kind in enumerate(kinds):
+                    h, a = self._apply_layer(kind, layer_params[f"L{i}"], h,
+                                             positions, memory)
+                    aux = aux + a
+                return (h, aux), None
 
-        body = self._remat_wrap(body)
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                   params["layers"])
-        return x, aux
+            return jax.lax.scan(self._remat_wrap(body), carry, stacked)[0]
+
+        carry = (x, jnp.zeros((), jnp.float32))
+        if self.lead:
+            carry = scan(self.lead, carry, params["dense_layers"])
+        return scan(self.pattern, carry, params["layers"])
 
     def _encode(self, params, frames):
         """Whisper-style encoder over stub frame embeddings (B,M,D)."""
@@ -198,15 +201,15 @@ class StackModel:
 
         body = self._remat_wrap(body)
         x, _ = jax.lax.scan(body, x, params["encoder"])
-        return L.apply_norm(params["enc_norm"], x)
+        return self._norm(params["enc_norm"], x)
 
     def _apply_layer_generic(self, p, x, mixer, mlp):
         positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
-        h = L.apply_norm(p["norm1"], x)
+        h = self._norm(p["norm1"], x)
         y, _ = attn.apply_self_attn(p["mixer"], self.cfg, h, positions,
                                     shard=self.sharder, causal=False)
         x = x + y
-        h = L.apply_norm(p["norm2"], x)
+        h = self._norm(p["norm2"], x)
         x = x + L.apply_mlp(p["mlp"], h)
         return x, None
 
@@ -228,7 +231,7 @@ class StackModel:
         positions = jnp.arange(s, dtype=jnp.int32)[None, :]
         memory = self._memory_of(params, batch)
         x, aux = self._run_stack(params, x, positions, memory)
-        x = L.apply_norm(params["final_norm"], x)
+        x = self._norm(params["final_norm"], x)
         logits = L.unembed(params["embed"], x, cfg.logits_softcap, cfg.vocab_size)
         return logits, aux
 
@@ -241,9 +244,8 @@ class StackModel:
     # ------------------------------------------------------------------
     # Serving: prefill + decode
     # ------------------------------------------------------------------
-    def _layer_cache_spec(self, i: int, batch: int, seq: int):
+    def _layer_cache_spec(self, mixer: str, batch: int, seq: int):
         cfg = self.cfg
-        mixer, _ = self.pattern[i]
         kvs = attn.kv_cache_shape(cfg, batch, seq)
         ca = ("batch", "cache_seq", "kv_heads", "head_dim")
         if mixer in ("attn",):
@@ -273,24 +275,97 @@ class StackModel:
         return self.dtype
 
     def cache_spec(self, batch: int, seq: int):
-        """Returns (shape_tree, logical_axes_tree) for the decode cache."""
-        cfg = self.cfg
-        shapes, axes = {}, {}
-        for i in range(len(self.pattern)):
-            entry = self._layer_cache_spec(i, batch, seq)
-            shapes[f"L{i}"] = {k: jax.ShapeDtypeStruct((cfg.num_periods,) + shp, dt)
-                               for k, (shp, ax, dt) in entry.items()}
-            axes[f"L{i}"] = {k: (None,) + ax for k, (shp, ax, dt) in entry.items()}
+        """Returns (shape_tree, logical_axes_tree) for the decode cache: per
+        layer of the period ``L<i>``, stacked over the periods, and the
+        leading dense layers' under ``dense_layers``."""
+        def group(kinds, n):
+            shapes, axes = {}, {}
+            for i, (mixer, _) in enumerate(kinds):
+                entry = self._layer_cache_spec(mixer, batch, seq)
+                shapes[f"L{i}"] = {k: jax.ShapeDtypeStruct((n,) + shp, dt)
+                                   for k, (shp, ax, dt) in entry.items()}
+                axes[f"L{i}"] = {k: (None,) + ax for k, (shp, ax, dt) in entry.items()}
+            return shapes, axes
+
+        shapes, axes = group(self.pattern, self.cfg.num_periods)
+        if self.lead:
+            shapes["dense_layers"], axes["dense_layers"] = group(
+                self.lead, self.cfg.first_dense_layers)
         return shapes, axes
 
     def init_cache(self, batch: int, seq: int):
         shapes, _ = self.cache_spec(batch, seq)
         return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
 
+    def _mlp_block(self, mlp: str, p, h):
+        """The residual MLP branch of one layer -> (h, the MoE aux loss)."""
+        aux = jnp.zeros((), jnp.float32)
+        if mlp == "none":
+            return h, aux
+        hn = self._norm(p["norm2"], h)
+        if mlp == "dense":
+            y = L.apply_mlp(p["mlp"], hn)
+        else:
+            y, aux = moe_lib.apply_moe(p["mlp"], self.cfg, hn, shard=self.sharder)
+            if mlp == "moe_dense":
+                y = y + L.apply_mlp(p["mlp_dense"], hn)
+        return h + y, aux
+
+    def _prefill_layer(self, kind, p, h, positions, memory):
+        cfg = self.cfg
+        mixer, mlp = kind
+        hn = self._norm(p["norm1"], h)
+        cache_i = {}
+        if mixer == "attn":
+            y, (k, v) = attn.apply_self_attn(p["mixer"], cfg, hn, positions)
+            cache_i = {"k": k.astype(self._cache_dtype),
+                       "v": v.astype(self._cache_dtype)}
+        elif mixer == "cross":
+            y, (k, v) = attn.apply_cross_attn(p["mixer"], cfg, hn, memory)
+            y = y * jnp.tanh(p["gate_attn"]).astype(y.dtype)
+            cache_i = {"k": k.astype(self._cache_dtype),
+                       "v": v.astype(self._cache_dtype)}
+        elif mixer == "attn_cross":
+            y, (k, v) = attn.apply_self_attn(p["mixer"], cfg, hn, positions)
+            h = h + y
+            hx = self._norm(p["norm_x"], h)
+            y, (xk, xv) = attn.apply_cross_attn(p["cross"], cfg, hx, memory)
+            cache_i = {"k": k.astype(self._cache_dtype),
+                       "v": v.astype(self._cache_dtype),
+                       "xk": xk.astype(self._cache_dtype),
+                       "xv": xv.astype(self._cache_dtype)}
+        elif mixer == "ssm":
+            y, cache_i = ssm_lib.apply_ssm(p["mixer"], cfg, hn, return_cache=True)
+        h, _ = self._mlp_block(mlp, p, h + y)
+        return self._constrain(h, "acts"), cache_i
+
+    def _decode_layer(self, kind, p, c, h, pos):
+        cfg = self.cfg
+        mixer, mlp = kind
+        hn = self._norm(p["norm1"], h)
+        if mixer == "attn":
+            y, c = attn.decode_self_attn(p["mixer"], cfg, hn, c, pos,
+                                         shard=self.sharder)
+        elif mixer == "cross":
+            y, c = attn.decode_cross_attn(p["mixer"], cfg, hn, c)
+            y = y * jnp.tanh(p["gate_attn"]).astype(y.dtype)
+        elif mixer == "attn_cross":
+            y, sc = attn.decode_self_attn(
+                p["mixer"], cfg, hn, {"k": c["k"], "v": c["v"]}, pos,
+                shard=self.sharder)
+            h = h + y
+            hx = self._norm(p["norm_x"], h)
+            y, _ = attn.decode_cross_attn(
+                p["cross"], cfg, hx, {"k": c["xk"], "v": c["xv"]})
+            c = {**sc, "xk": c["xk"], "xv": c["xv"]}
+        elif mixer == "ssm":
+            y, c = ssm_lib.decode_ssm(p["mixer"], cfg, hn, c)
+        return self._mlp_block(mlp, p, h + y)[0], c
+
     def prefill(self, params, batch):
         """Full-sequence forward that also builds the decode cache.
 
-        Returns (last_token_logits, cache, aux).
+        Returns (last_token_logits, cache).
         """
         cfg = self.cfg
         tokens = batch["tokens"]
@@ -299,52 +374,22 @@ class StackModel:
         positions = jnp.arange(s, dtype=jnp.int32)[None, :]
         memory = self._memory_of(params, batch)
 
-        def body(h, layer_params):
-            caches = {}
-            for i, (mixer, mlp) in enumerate(self.pattern):
-                p = layer_params[f"L{i}"]
-                hn = L.apply_norm(p["norm1"], h)
-                cache_i = {}
-                if mixer == "attn":
-                    y, (k, v) = attn.apply_self_attn(p["mixer"], cfg, hn, positions)
-                    cache_i = {"k": k.astype(self._cache_dtype),
-                               "v": v.astype(self._cache_dtype)}
-                elif mixer == "cross":
-                    y, (k, v) = attn.apply_cross_attn(p["mixer"], cfg, hn, memory)
-                    y = y * jnp.tanh(p["gate_attn"]).astype(y.dtype)
-                    cache_i = {"k": k.astype(self._cache_dtype),
-                               "v": v.astype(self._cache_dtype)}
-                elif mixer == "attn_cross":
-                    y, (k, v) = attn.apply_self_attn(p["mixer"], cfg, hn, positions)
-                    h = h + y
-                    hx = L.apply_norm(p["norm_x"], h)
-                    y, (xk, xv) = attn.apply_cross_attn(p["cross"], cfg, hx, memory)
-                    cache_i = {"k": k.astype(self._cache_dtype),
-                               "v": v.astype(self._cache_dtype),
-                               "xk": xk.astype(self._cache_dtype),
-                               "xv": xv.astype(self._cache_dtype)}
-                elif mixer == "ssm":
-                    y, ssm_cache = ssm_lib.apply_ssm(p["mixer"], cfg, hn,
-                                                     return_cache=True)
-                    cache_i = ssm_cache
-                h = h + y
-                if mlp != "none":
-                    hn = L.apply_norm(p["norm2"], h)
-                    if mlp == "dense":
-                        y = L.apply_mlp(p["mlp"], hn)
-                    elif mlp == "moe":
-                        y, _ = moe_lib.apply_moe(p["mlp"], cfg, hn, shard=self.sharder)
-                    else:
-                        y, _ = moe_lib.apply_moe(p["mlp"], cfg, hn, shard=self.sharder)
-                        y = y + L.apply_mlp(p["mlp_dense"], hn)
-                    h = h + y
-                h = self._constrain(h, "acts")
-                caches[f"L{i}"] = cache_i
-            return h, caches
+        def scan(kinds, h, stacked):
+            def body(h, layer_params):
+                caches = {}
+                for i, kind in enumerate(kinds):
+                    h, caches[f"L{i}"] = self._prefill_layer(
+                        kind, layer_params[f"L{i}"], h, positions, memory)
+                return h, caches
 
-        body = self._remat_wrap(body)
-        x, cache = jax.lax.scan(body, x, params["layers"])
-        x = L.apply_norm(params["final_norm"], x)
+            return jax.lax.scan(self._remat_wrap(body), h, stacked)
+
+        if self.lead:
+            x, lead_cache = scan(self.lead, x, params["dense_layers"])
+        x, cache = scan(self.pattern, x, params["layers"])
+        if self.lead:
+            cache["dense_layers"] = lead_cache
+        x = self._norm(params["final_norm"], x)
         logits = L.unembed(params["embed"], x[:, -1:], cfg.logits_softcap, cfg.vocab_size)
         return logits, cache
 
@@ -353,44 +398,24 @@ class StackModel:
         cfg = self.cfg
         x = L.embed_tokens(params["embed"], tokens, self.dtype)
 
-        def body(h, xs):
-            layer_params, layer_cache = xs
-            new_cache = {}
-            for i, (mixer, mlp) in enumerate(self.pattern):
-                p, c = layer_params[f"L{i}"], layer_cache[f"L{i}"]
-                hn = L.apply_norm(p["norm1"], h)
-                if mixer == "attn":
-                    y, c = attn.decode_self_attn(p["mixer"], cfg, hn, c, pos,
-                                                 shard=self.sharder)
-                elif mixer == "cross":
-                    y, c = attn.decode_cross_attn(p["mixer"], cfg, hn, c)
-                    y = y * jnp.tanh(p["gate_attn"]).astype(y.dtype)
-                elif mixer == "attn_cross":
-                    y, sc = attn.decode_self_attn(
-                        p["mixer"], cfg, hn, {"k": c["k"], "v": c["v"]}, pos,
-                        shard=self.sharder)
-                    h = h + y
-                    hx = L.apply_norm(p["norm_x"], h)
-                    y, _ = attn.decode_cross_attn(
-                        p["cross"], cfg, hx, {"k": c["xk"], "v": c["xv"]})
-                    c = {**sc, "xk": c["xk"], "xv": c["xv"]}
-                elif mixer == "ssm":
-                    y, c = ssm_lib.decode_ssm(p["mixer"], cfg, hn, c)
-                h = h + y
-                if mlp != "none":
-                    hn = L.apply_norm(p["norm2"], h)
-                    if mlp == "dense":
-                        y = L.apply_mlp(p["mlp"], hn)
-                    elif mlp == "moe":
-                        y, _ = moe_lib.apply_moe(p["mlp"], cfg, hn, shard=self.sharder)
-                    else:
-                        y, _ = moe_lib.apply_moe(p["mlp"], cfg, hn, shard=self.sharder)
-                        y = y + L.apply_mlp(p["mlp_dense"], hn)
-                    h = h + y
-                new_cache[f"L{i}"] = c
-            return h, new_cache
+        def scan(kinds, h, stacked, caches):
+            def body(h, xs):
+                layer_params, layer_cache = xs
+                new_cache = {}
+                for i, kind in enumerate(kinds):
+                    h, new_cache[f"L{i}"] = self._decode_layer(
+                        kind, layer_params[f"L{i}"], layer_cache[f"L{i}"], h, pos)
+                return h, new_cache
 
-        x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
-        x = L.apply_norm(params["final_norm"], x)
+            return jax.lax.scan(body, h, (stacked, caches))
+
+        cache = dict(cache)
+        lead_cache = cache.pop("dense_layers", None)
+        if self.lead:
+            x, lead_cache = scan(self.lead, x, params["dense_layers"], lead_cache)
+        x, new_cache = scan(self.pattern, x, params["layers"], cache)
+        if self.lead:
+            new_cache["dense_layers"] = lead_cache
+        x = self._norm(params["final_norm"], x)
         logits = L.unembed(params["embed"], x, cfg.logits_softcap, cfg.vocab_size)
         return logits, new_cache

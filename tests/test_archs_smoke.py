@@ -124,6 +124,7 @@ def test_param_counts_match_published():
         "jamba-v0.1-52b": 52e9, "arctic-480b": 480e9, "chatglm3-6b": 6.2e9,
         "phi4-mini-3.8b": 3.8e9, "qwen2.5-32b": 32.5e9, "qwen2-0.5b": 0.5e9,
         "llama-3.2-vision-90b": 88e9, "mamba2-370m": 0.37e9,
+        "moonlight-16b-a3b": 15.96e9,
     }
     for arch, want in expected.items():
         n = build_model(get_config(arch)).param_count()

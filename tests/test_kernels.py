@@ -35,11 +35,11 @@ def test_flash_attention_allclose(b, s, h, hd, dtype, causal):
 
 def _attention_ref_gqa(q, k, v, causal=True):
     """``ref.attention_ref`` in model layout, K/V repeated over each group."""
-    b, s, h, hd = q.shape
+    b, s, h, _ = q.shape
     flat = lambda t: jnp.moveaxis(jnp.repeat(t, h // t.shape[2], axis=2), 2,
-                                  1).reshape(b * h, s, hd)
+                                  1).reshape(b * h, s, t.shape[-1])
     o = ref.attention_ref(flat(q), flat(k), flat(v), causal)
-    return jnp.moveaxis(o.reshape(b, h, s, hd), 1, 2)
+    return jnp.moveaxis(o.reshape(b, h, s, v.shape[-1]), 1, 2)
 
 
 @pytest.mark.parametrize("group", [1, 7])
@@ -70,6 +70,37 @@ def test_flash_attention_grads_match_reference(group, hd, s, blocks, dtype):
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
     for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
         assert g.dtype == dtype, name
+        err = float(jnp.max(jnp.abs(f32(g) - w)) / jnp.max(jnp.abs(w)))
+        assert err <= tol, (name, err)
+
+
+@pytest.mark.parametrize("dqk,dv", [(48, 32), (192, 128)])
+@pytest.mark.parametrize("group,s", [(1, 128), (2, 256)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_value_width_differs(dqk, dv, group, s, dtype):
+    """Latent attention's shapes: q/k heads wider than v heads (192/128 as
+    published, 48/32 reduced). Forward and dQ, dK, dV against the f32
+    reference, scaled by 1/sqrt(d_qk)."""
+    q = _rand(1, (1, s, 2 * group, dqk), dtype)
+    k = _rand(2, (1, s, 2, dqk), dtype)
+    v = _rand(3, (1, s, 2, dv), dtype)
+    do = _rand(4, (1, s, 2 * group, dv), dtype)
+    f32 = lambda t: t.astype(jnp.float32)
+
+    def out_and_grads(attn):
+        def f(q, k, v):
+            o, vjp = jax.vjp(attn, q, k, v)
+            return (o, *vjp(do.astype(o.dtype)))
+        return jax.jit(f)
+
+    kernel = lambda q, k, v: ops.flash_attention(
+        q, k, v, blocks=(64, 64) if s > 128 else None, interpret=True)
+    got = out_and_grads(kernel)(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        want = out_and_grads(_attention_ref_gqa)(f32(q), f32(k), f32(v))
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        assert g.shape == w.shape and g.dtype == dtype, name
         err = float(jnp.max(jnp.abs(f32(g) - w)) / jnp.max(jnp.abs(w)))
         assert err <= tol, (name, err)
 
@@ -214,6 +245,7 @@ def test_auto_takes_xla_on_the_cpu():
     (2048, 1024, 64, False),   # not square
     (1152, 1152, 64, True), (1100, 1100, 64, False),  # 128 tiles 1152 only
     (2048, 2048, 80, False),   # a head width the kernel is not built for
+    (8192, 8192, 192, True),   # latent attention's q/k heads
     (256, 256, 64, False),     # under FLASH_MIN_SEQ
 ])
 def test_auto_shape_rule_on_a_tpu(monkeypatch, sq, sk, hd, takes):
